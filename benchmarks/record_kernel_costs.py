@@ -10,9 +10,11 @@ them under the `batched+kernel` backend axis (`cost_table.backend_name`).
 
     PYTHONPATH=src python -m benchmarks.record_kernel_costs [--dry-run]
 
-On CPU the kernel runs under the Pallas interpreter (the capability-
-probed default), so the recorded costs price exactly what a CPU stream
-would dispatch; on TPU/GPU the same command records the compiled kernel.
+On CPU the kernel runs under the Pallas interpreter, so the recorded
+costs price exactly what a CPU stream would dispatch; on TPU/GPU the
+same command records the compiled kernel. The mode is chosen here and
+passed explicitly (`kernel_interpret`), so a platform that should
+compile the kernel but cannot fails instead of interpreting.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ import numpy as np
 from repro.core.camera import CameraModel
 from repro.core.dsi import DSIConfig
 from repro.core.pipeline import EMVSOptions, SegmentBatch, sweep_segment_batch
+from repro.kernels.platform import compiled_kernels_supported
 from repro.profiling.cost_table import CostTable, VariantKey, backend_name
 
 # the (s_bucket, capacity) points the matmul rows already cover
@@ -57,12 +60,15 @@ def record(table: CostTable, *, events: int, repeats: int,
     cam = CameraModel()
     dsi_cfg = DSIConfig.for_camera(cam, num_planes=32)
     backend = backend_name("batched", "kernel")
+    interpret = not compiled_kernels_supported()
+    print(f"kernel mode: {'interpreted' if interpret else 'compiled'}",
+          flush=True)
     rows = []
     jobs = [(s, c, False) for s, c in grid]
     jobs += [(s, c, True) for s, c in quantized_points]
     for s, c, quantized in jobs:
         opts = EMVSOptions(voting="nearest", formulation="kernel",
-                           quantized=quantized)
+                           quantized=quantized, kernel_interpret=interpret)
         batch = _synthetic_batch(s, c, events, cam)
         key = VariantKey(s_bucket=s, capacity=c, backend=backend,
                          interpolation="nearest", quantized=quantized)
@@ -115,4 +121,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

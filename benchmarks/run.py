@@ -52,4 +52,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -7,15 +7,15 @@ concurrent key-frame segments vote on different devices — the paper's
 key-frame-level parallelism, the axis a serial sweep cannot exploit.
 
 On a real multi-chip backend the sharded path buys near-linear
-cross-segment speedup; on a CPU host with forced host devices
-(`--devices N`, XLA's host-platform partitioning) the devices share the
-same cores, so the interesting outputs here are (a) the bitwise
+cross-segment speedup over the devices JAX finds; on a CPU host with
+forced host devices (`--devices N`, XLA's host-platform partitioning,
+the only case that touches XLA_FLAGS) the devices share the same cores, so the interesting outputs here are (a) the bitwise
 nearest-datapath equality check between the two backends and (b) the
 machine-readable segments/s trajectory in BENCH_emvs.json. Both paths
 are measured cold (fresh jit caches) and warm.
 
     PYTHONPATH=src python benchmarks/sharded_sweep.py [--dry-run]
-        [--devices 8] [--json-out BENCH_emvs.json]
+        [--devices N] [--json-out BENCH_emvs.json]
 """
 from __future__ import annotations
 
@@ -28,15 +28,16 @@ def _parse_args():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dry-run", action="store_true",
                     help="tiny sequence for CI smoke (same code path)")
-    ap.add_argument("--devices", type=int, default=8,
-                    help="forced host device count (0 = leave XLA alone)")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="force N host (CPU) devices; without it the "
+                         "sweep shards over the devices JAX finds")
     ap.add_argument("--json-out", default=None,
                     help="BENCH_emvs.json path (default: repo cwd)")
     return ap.parse_args()
 
 
 ARGS = _parse_args()
-if ARGS.devices > 0:
+if ARGS.devices is not None:
     # must precede any jax import: jax locks the device count on first init
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={ARGS.devices} "
@@ -160,4 +161,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

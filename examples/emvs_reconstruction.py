@@ -24,6 +24,7 @@ from repro.events.simulator import (
     SceneConfig, absrel, ground_truth_depth, make_scene, make_trajectory,
     simulate_events,
 )
+from repro.kernels.platform import compiled_kernels_supported
 
 
 def main() -> None:
@@ -48,6 +49,10 @@ def main() -> None:
     print(f"scene={args.scene}: {int(events.valid.sum())} events, "
           f"{frames.xy.shape[0]} frames, DSI {dsi_cfg.shape}")
 
+    # say which kernel mode runs: compiled where the platform can, the
+    # Pallas interpreter elsewhere
+    kernel_mode = ("compiled" if compiled_kernels_supported()
+                   else "interpreted")
     variants = {
         "scatter/float (original EMVS)": EMVSOptions(
             voting="bilinear", formulation="scatter"),
@@ -55,8 +60,9 @@ def main() -> None:
             voting="nearest", formulation="matmul"),
         "matmul/nearest + Table-1 quantization": EMVSOptions(
             voting="nearest", formulation="matmul", quantized=True),
-        "Pallas kernel (interpret) + quantization": EMVSOptions(
-            voting="nearest", formulation="kernel", quantized=True),
+        f"Pallas kernel ({kernel_mode}) + quantization": EMVSOptions(
+            voting="nearest", formulation="kernel", quantized=True,
+            kernel_interpret=kernel_mode == "interpreted"),
     }
     results = {}
     for name, opts in variants.items():

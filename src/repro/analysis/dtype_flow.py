@@ -21,7 +21,7 @@ propagating, per intermediate value:
   than the dtype-range default, so overflow findings are proofs, not
   guesses about unconstrained inputs.
 
-Control-flow and staging primitives (pjit, scan, while, cond,
+Control-flow and staging primitives (jit, scan, while, cond,
 shard_map, pallas_call, custom_jvp/vjp) are recursed into with the
 enclosing call stack recorded for finding provenance.  Pallas kernel
 bodies are interpreted best-effort over a Ref environment (``get`` /
@@ -231,7 +231,7 @@ class DtypeFlowAnalyzer:
             write(v, c)
         for v, a in zip(jaxpr.invars, args):
             # Re-anchor the contract interval on the inner aval's dtype and
-            # shape (shard_map narrows shapes; pjit may differ in weak_type).
+            # shape (shard_map narrows shapes; jit may differ in weak_type).
             inner = absval_from_aval(v.aval)
             write(
                 v,
@@ -716,14 +716,14 @@ class DtypeFlowAnalyzer:
         finally:
             self.ctx.call_stack.pop()
 
-    def _prim_pjit(self, eqn, ins):
+    def _prim_jit(self, eqn, ins):
         closed = eqn.params["jaxpr"]
-        name = eqn.params.get("name", "pjit")
+        name = eqn.params.get("name", "jit")
         consts = [absval_from_literal(c) for c in closed.consts]
-        return self._recurse(f"pjit:{name}", closed.jaxpr, consts, ins)
+        return self._recurse(f"jit:{name}", closed.jaxpr, consts, ins)
 
     def _prim_closed_call(self, eqn, ins):
-        closed = eqn.params.get("call_jaxpr") or eqn.params.get("jaxpr")
+        closed = eqn.params["call_jaxpr"]
         consts = [absval_from_literal(c) for c in closed.consts]
         return self._recurse("closed_call", closed.jaxpr, consts, ins)
 
@@ -733,17 +733,13 @@ class DtypeFlowAnalyzer:
         return self._recurse("custom_jvp", closed.jaxpr, consts, ins)
 
     def _prim_custom_vjp_call(self, eqn, ins):
-        closed = eqn.params.get("call_jaxpr") or eqn.params.get("fun_jaxpr")
+        closed = eqn.params["call_jaxpr"]
         consts = [absval_from_literal(c) for c in closed.consts]
         return self._recurse("custom_vjp", closed.jaxpr, consts, ins)
 
-    _prim_custom_vjp_call_jaxpr = _prim_custom_vjp_call
-
-    def _prim_remat(self, eqn, ins):
+    def _prim_remat2(self, eqn, ins):
         jaxpr = eqn.params["jaxpr"]
         return self._recurse("remat", jaxpr, [], ins)
-
-    _prim_checkpoint = _prim_remat
 
     def _prim_cond(self, eqn, ins):
         branches = eqn.params["branches"]

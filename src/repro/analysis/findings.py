@@ -3,7 +3,7 @@
 A ``Finding`` is one rule violation with full jaxpr provenance: the
 primitive, the source line of the offending equation (via JAX's
 ``source_info``), and the enclosing call stack the interpreter
-maintained while recursing through pjit / scan / shard_map /
+maintained while recursing through jit / scan / shard_map /
 pallas_call bodies.
 
 Suppression is baseline-driven: every finding has a stable
@@ -25,7 +25,7 @@ class Provenance:
 
     primitive: str  # jaxpr primitive name, e.g. "convert_element_type"
     source: str  # summarized source_info, e.g. "core/voting.py:155 (vote_scatter)"
-    call_stack: tuple[str, ...] = ()  # enclosing pjit/scan/shard_map bodies, outermost first
+    call_stack: tuple[str, ...] = ()  # enclosing jit/scan/shard_map bodies, outermost first
     eqn: str = ""  # pretty-printed equation (truncated)
 
     def render(self) -> str:

@@ -158,9 +158,7 @@ HOST_SYNC_PRIMITIVES = frozenset(
         "pure_callback",
         "io_callback",
         "debug_callback",
-        "callback",
-        "host_callback_call",
-        "outside_call",
+        "debug_print",
         "infeed",
         "outfeed",
     }

@@ -32,6 +32,7 @@ Derivation of phi (matches the paper's 2-MAC-per-plane structure):
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -40,6 +41,13 @@ import jax.numpy as jnp
 from repro.core.camera import CameraModel
 
 Array = jax.Array
+
+# The 3x3 products below run in full float32. At default precision a TPU
+# multiplies f32 matrices in one bfloat16 pass, which moves the
+# homography K @ H @ K^-1 (fx ~ 200, cx ~ 120) by up to half a pixel and
+# flips nearest-voxel votes; these products are tiny, so exactness is free.
+HIGHEST = jax.lax.Precision.HIGHEST
+_mm = partial(jnp.matmul, precision=HIGHEST)
 
 
 class SE3(NamedTuple):
@@ -56,15 +64,16 @@ class SE3(NamedTuple):
 
     def compose(self, other: "SE3") -> "SE3":
         """self ∘ other: apply `other` first, then `self`."""
-        return SE3(self.R @ other.R, (self.R @ other.t[..., None])[..., 0] + self.t)
+        return SE3(_mm(self.R, other.R), _mm(self.R, other.t[..., None])[..., 0] + self.t)
 
     def inverse(self) -> "SE3":
         Rt = jnp.swapaxes(self.R, -1, -2)
-        return SE3(Rt, -(Rt @ self.t[..., None])[..., 0])
+        return SE3(Rt, -_mm(Rt, self.t[..., None])[..., 0])
 
     def apply(self, points: Array) -> Array:
         """points: (..., 3) -> transformed (..., 3)."""
-        return jnp.einsum("...ij,...nj->...ni", self.R, points) + self.t[..., None, :]
+        return (jnp.einsum("...ij,...nj->...ni", self.R, points, precision=HIGHEST)
+                + self.t[..., None, :])
 
 
 def so3_exp(w: Array) -> Array:
@@ -85,7 +94,7 @@ def so3_exp(w: Array) -> Array:
     K = K / safe[..., 0, 0][..., None, None]
     eye = jnp.broadcast_to(jnp.eye(3, dtype=w.dtype), K.shape)
     sin_t, cos_t = jnp.sin(theta[..., 0, 0]), jnp.cos(theta[..., 0, 0])
-    R = eye + sin_t[..., None, None] * K + (1.0 - cos_t)[..., None, None] * (K @ K)
+    R = eye + sin_t[..., None, None] * K + (1.0 - cos_t)[..., None, None] * _mm(K, K)
     return jnp.where(theta < 1e-8, eye, R)
 
 
@@ -99,9 +108,9 @@ def interpolate_pose(p0: SE3, p1: SE3, frac: Array) -> SE3:
     """
     t = p0.t + frac * (p1.t - p0.t)
     # relative rotation
-    dR = p1.R @ jnp.swapaxes(p0.R, -1, -2)
+    dR = _mm(p1.R, jnp.swapaxes(p0.R, -1, -2))
     w = so3_log(dR)
-    R = so3_exp(w * frac) @ p0.R
+    R = _mm(so3_exp(w * frac), p0.R)
     return SE3(R, t)
 
 
@@ -154,10 +163,10 @@ def canonical_homography(cam: CameraModel, T_ref_cam: SE3, z0: Array) -> Array:
     """
     R_rc, t_rc = T_ref_cam.R, T_ref_cam.t
     e_z = jnp.array([0.0, 0.0, 1.0], dtype=jnp.float32)
-    n_c = R_rc.T @ e_z  # plane normal in current frame
-    d_c = z0 - e_z @ t_rc  # plane offset along ray in current frame
+    n_c = _mm(R_rc.T, e_z)  # plane normal in current frame
+    d_c = z0 - _mm(e_z, t_rc)  # plane offset along ray in current frame
     H_metric = R_rc + jnp.outer(t_rc, n_c) / d_c
-    H = cam.K @ H_metric @ cam.K_inv
+    H = _mm(_mm(cam.K, H_metric), cam.K_inv)
     return (H / H[2, 2]).astype(jnp.float32)
 
 

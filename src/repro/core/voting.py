@@ -152,7 +152,11 @@ def vote_onehot_matmul(
         raise ValueError(f"unknown voting mode: {mode}")
     if weights is not None:
         ox = ox * weights[..., None]
-    votes = jnp.einsum("zeh,zew->zhw", oy, ox)  # MXU contraction over events
+    # fractional bilinear weights are contracted in full f32 (a TPU's
+    # default is one bf16 pass); 0/1 nearest rows are exact at any precision
+    precision = jax.lax.Precision.HIGHEST if mode == "bilinear" else None
+    votes = jnp.einsum("zeh,zew->zhw", oy, ox,
+                       precision=precision)  # MXU contraction over events
     if dsi.dtype in (jnp.int16, jnp.int32):
         # RTL rounding convention: half away from zero, matching the
         # fixed-point quantizers — jnp.round would be half-to-even and

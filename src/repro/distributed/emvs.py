@@ -42,8 +42,7 @@ from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.camera import CameraModel
 from repro.core.detection import DepthMap, detect_structure
@@ -142,8 +141,8 @@ def make_emvs_step(cam: CameraModel, dsi_cfg: DSIConfig, mesh: Mesh, *,
         out_specs = (P(pod_axis, model_axis, None, None), P(pod_axis),
                      P(pod_axis), P(pod_axis))
 
-    return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def emvs_input_specs(dsi_cfg: DSIConfig, *, frames: int, events: int,
@@ -182,10 +181,13 @@ def make_segment_mesh(devices=None) -> Mesh:
     """1-D mesh over all (or the given) devices with the segment axis.
 
     The default backend mesh for `run_emvs(sweep="sharded")` and
-    `EMVSStreamEngine` with `StreamConfig(sweep="sharded")`.
+    `EMVSStreamEngine` with `StreamConfig(sweep="sharded")`. The axis is
+    `Auto`: the sweep's outputs feed unsharded jits (the point cloud's
+    vmap), which `Explicit` axes (`jax.make_mesh`'s default) would refuse.
     """
     devs = list(jax.devices()) if devices is None else list(devices)
-    return jax.make_mesh((len(devs),), (SEGMENT_AXIS,), devices=devs)
+    return jax.make_mesh((len(devs),), (SEGMENT_AXIS,),
+                         axis_types=(AxisType.Auto,), devices=devs)
 
 
 def segment_axis_size(mesh: Mesh, axis_name: str = SEGMENT_AXIS) -> int:
@@ -222,8 +224,8 @@ def _sharded_sweep_fn(cam: CameraModel, dsi_cfg: DSIConfig, opts: EMVSOptions,
 
     # A single PartitionSpec acts as a pytree prefix: every SegmentBatch
     # leaf (and every output leaf) shards its leading segment axis.
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec,),
-                             out_specs=spec, check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
 
 
 def process_segments_sharded(

@@ -21,7 +21,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import ArchConfig
@@ -107,20 +106,20 @@ class EPShard:
                 metrics = {k: jax.lax.pmean(v, m) for k, v in metrics.items()}
                 return y, metrics
 
-            fn = shard_map(body, mesh=self.mesh,
-                           in_specs=(p_specs, x_spec),
-                           out_specs=(x_spec, {"moe_aux": P(), "moe_drop_frac": P()}),
-                           check_rep=False)
+            fn = jax.shard_map(body, mesh=self.mesh,
+                               in_specs=(p_specs, x_spec),
+                               out_specs=(x_spec, {"moe_aux": P(), "moe_drop_frac": P()}),
+                               check_vma=False)
             return fn(params, x)
 
         def body_a2a(p, xt):
             return _moe_all_to_all(zero3_gather(p), xt, cfg, m, ep_size)
 
-        fn = shard_map(body_a2a, mesh=self.mesh,
-                       in_specs=(p_specs, P((self.token_axes + (m,)), None)),
-                       out_specs=(P((self.token_axes + (m,)), None),
-                                  {"moe_aux": P(), "moe_drop_frac": P()}),
-                       check_rep=False)
+        fn = jax.shard_map(body_a2a, mesh=self.mesh,
+                           in_specs=(p_specs, P((self.token_axes + (m,)), None)),
+                           out_specs=(P((self.token_axes + (m,)), None),
+                                      {"moe_aux": P(), "moe_drop_frac": P()}),
+                           check_vma=False)
         return fn(params, x)
 
 

@@ -15,7 +15,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -62,11 +61,11 @@ class SeqShard:
             out = acc / jnp.maximum(l, 1e-30)
             return out.reshape(b, 1, hq, d).astype(q.dtype)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(None, self.seq_axis, None, None),
                       P(None, self.seq_axis, None, None), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(q, k, v, jnp.asarray(length))

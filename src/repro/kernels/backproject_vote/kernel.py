@@ -27,7 +27,7 @@ Vote Execute Unit), re-architected for the TPU memory hierarchy:
 
 Tiling: the full (h_pad, w_pad) plane tile lives in VMEM
 (184*256*4 B = 188 KiB) — the DAVIS-scale DSI plane is small relative to
-VMEM (~16 MiB), so we tile over depth, not space. Votes accumulate in a
+the 16 MiB scoped-VMEM budget, so we tile over depth, not space. Votes accumulate in a
 float32 VMEM scratch block revisited across all frames (axis 1 minor);
 on the last frame step the block is stored (int16 saturating when
 quantized) and folded into the detection state, so the stored DSI makes
@@ -35,9 +35,11 @@ exactly one HBM trip and the max/argmax never reads it back — the no-
 DRAM-round-trip datapath the paper's speedup comes from
 (docs/kernel_fusion.md walks the stages and the VMEM budget).
 
-The event-index contraction (E or F_STEP*E) feeds the MXU with a
-(h_pad, E) x (E, w_pad) matmul per plane — systolic-friendly dims
-(multiples of 8/128 via padding).
+Events arrive as (F, 1, E) rows, events on lanes, and the one-hot
+factors are built transposed, (pixels, E): the event-index contraction
+feeds the MXU one (h_pad, E) x (w_pad, E)^T matmul per plane and frame —
+systolic-friendly dims (multiples of 8/128 via padding), and no
+lane-to-sublane relayout (docs/kernel_fusion.md, "Block layout").
 
 Detection semantics are bitwise those of `kernels/local_max` (and hence
 of `core/detection.detect_structure`): first-max-wins streaming argmax
@@ -74,9 +76,9 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _kernel(
-    x_ref,  # (FS, E) raw canonical x coords for FS frames
-    y_ref,  # (FS, E)
-    valid_ref,  # (FS, E) float32 1/0
+    x_ref,  # (FS, 1, E) raw canonical x coords, events on lanes
+    y_ref,  # (FS, 1, E)
+    valid_ref,  # (FS, 1, E) float32 1/0
     phi_ref,  # (FS, BZ, 3) alpha, beta_x, beta_y  (per frame, per plane)
     dsi_ref,  # (BZ, h_pad, w_pad) stored DSI block (int16 when quantized)
     conf_ref,  # (h_pad, w_pad) float32 running max over z (output)
@@ -116,74 +118,84 @@ def _kernel(
         cnext_ref[...] = jnp.zeros_like(cnext_ref)
         pwb_ref[...] = jnp.zeros_like(pwb_ref)
 
-    e = x_ref.shape[1]
+    e = x_ref.shape[2]
     w_pad = acc_ref.shape[2]
     h_pad = acc_ref.shape[1]
 
-    # flatten the frame-step axis into the event contraction axis
-    x0 = x_ref[...].reshape(fs * e) - cx  # (FS*E,) centred canonical coords
-    y0 = y_ref[...].reshape(fs * e) - cy
-    vv = valid_ref[...].reshape(fs * e)
+    # pixel index down the sublanes, one event per lane: the one-hot rows
+    # are built transposed, (pixels, E), so an event row (1, E) broadcasts
+    # over sublanes and never moves between lanes and sublanes. Integer
+    # iotas (Mosaic has no float iota), converted once per grid step.
+    pix_x = jax.lax.broadcasted_iota(jnp.int32, (w_pad, e), 0).astype(jnp.float32)
+    pix_y = jax.lax.broadcasted_iota(jnp.int32, (h_pad, e), 0).astype(jnp.float32)
+    acc_type = jnp.int32 if onehot_dtype == jnp.int8 else jnp.float32
+    # fp32 rows (bilinear weights) are contracted exactly, not in one
+    # bf16 pass; 0/1 bf16/int8 rows are exact at any precision
+    precision = (jax.lax.Precision.HIGHEST if onehot_dtype == jnp.float32
+                 else None)
 
-    col_x = jax.lax.broadcasted_iota(jnp.float32, (fs * e, w_pad), 1)
-    col_y = jax.lax.broadcasted_iota(jnp.float32, (fs * e, h_pad), 1)
+    # one frame at a time: a rolled loop keeps one frame's factors live,
+    # where unrolling `fs` frames ran the bilinear variant out of VMEM
+    @pl.loop(0, fs)
+    def _frame(j):
+        x0 = x_ref[j] - cx  # (1, E) centred canonical coords
+        y0 = y_ref[j] - cy
+        vv = valid_ref[j]
+        for p in range(bz):
+            # P(Z0 -> Zi): one multiply-add per coordinate (the PE_Zi
+            # scalar MACs); the frame's (1, 1) coefficients broadcast
+            # over its events
+            alpha = phi_ref[j, p:p + 1, 0:1]
+            bx = phi_ref[j, p:p + 1, 1:2]
+            by = phi_ref[j, p:p + 1, 2:3]
+            xi = alpha * x0 + bx + cx
+            yi = alpha * y0 + by + cy
+            if quantized and mode == "nearest":
+                # Table 1: plane coords carry int8 — the SAME policy method
+                # as the XLA datapath (project_frame), applied in the same
+                # order (quantize BEFORE the vote sanitize), so the
+                # formulations agree bitwise by construction
+                from repro.quant.policies import TABLE1
 
-    for p in range(bz):
-        # P(Z0 -> Zi): one multiply-add per coordinate (the PE_Zi scalar MACs)
-        # phi is per-frame; broadcast each frame's coeffs over its events.
-        alpha = phi_ref[:, p, 0:1]  # (FS, 1)
-        bx = phi_ref[:, p, 1:2]
-        by = phi_ref[:, p, 2:3]
-        a_e = jnp.broadcast_to(alpha, (fs, e)).reshape(fs * e)
-        bx_e = jnp.broadcast_to(bx, (fs, e)).reshape(fs * e)
-        by_e = jnp.broadcast_to(by, (fs, e)).reshape(fs * e)
-        xi = a_e * x0 + bx_e + cx
-        yi = a_e * y0 + by_e + cy
-        if quantized and mode == "nearest":
-            # Table 1: plane coords carry int8 — the SAME policy method as
-            # the XLA datapath (project_frame), applied in the same order
-            # (quantize BEFORE the vote sanitize), so the formulations
-            # agree bitwise by construction
-            from repro.quant.policies import TABLE1
+                xi = TABLE1.quantize_plane_coord_values(xi)
+                yi = TABLE1.quantize_plane_coord_values(yi)
+            xi = jnp.clip(jnp.where(jnp.isfinite(xi), xi, -1e6), -1e6, 1e6)
+            yi = jnp.clip(jnp.where(jnp.isfinite(yi), yi, -1e6), -1e6, 1e6)
 
-            xi = TABLE1.quantize_plane_coord_values(xi)
-            yi = TABLE1.quantize_plane_coord_values(yi)
-        xi = jnp.clip(jnp.where(jnp.isfinite(xi), xi, -1e6), -1e6, 1e6)
-        yi = jnp.clip(jnp.where(jnp.isfinite(yi), yi, -1e6), -1e6, 1e6)
+            if mode == "nearest":
+                xr = jnp.floor(xi + 0.5)
+                yr = jnp.floor(yi + 0.5)
+                # miss judgement against the LOGICAL sensor bounds
+                ok = (xr >= 0) & (xr <= w - 1) & (yr >= 0) & (yr <= h - 1)
+                wt = vv * ok.astype(jnp.float32)
+                # int8 rows (§Perf E1): 0/1 one-hots and the 0/1 validity
+                # mask are exact in int8; the MXU's int8 path runs 2x bf16
+                ox = (pix_x == xr).astype(onehot_dtype) * wt.astype(onehot_dtype)
+                oy = (pix_y == yr).astype(onehot_dtype)
+            else:  # bilinear: separable two-hot rows
+                xf = jnp.floor(xi)
+                yf = jnp.floor(yi)
+                ok = ((xf >= 0) & (xf + 1 <= w - 1) & (yf >= 0)
+                      & (yf + 1 <= h - 1))
+                wt = (vv * ok.astype(jnp.float32)).astype(onehot_dtype)
+                fx = (xi - xf).astype(onehot_dtype)
+                fy = (yi - yf).astype(onehot_dtype)
+                ox = ((pix_x == xf).astype(onehot_dtype) * (1 - fx)
+                      + (pix_x == xf + 1).astype(onehot_dtype) * fx)
+                oy = ((pix_y == yf).astype(onehot_dtype) * (1 - fy)
+                      + (pix_y == yf + 1).astype(onehot_dtype) * fy)
+                ox = ox * wt
 
-        if mode == "nearest":
-            xr = jnp.floor(xi + 0.5)
-            yr = jnp.floor(yi + 0.5)
-            # miss judgement against the LOGICAL sensor bounds
-            ok = (xr >= 0) & (xr <= w - 1) & (yr >= 0) & (yr <= h - 1)
-            wt = vv * ok.astype(jnp.float32)
-            ox = (xr[:, None] == col_x).astype(onehot_dtype)
-            oy = (yr[:, None] == col_y).astype(onehot_dtype)
-            # int8 rows (§Perf E1): 0/1 one-hots and the 0/1 validity mask
-            # are exact in int8; the MXU's int8 path runs 2x bf16 rate
-            ox = ox * wt[:, None].astype(onehot_dtype)
-        else:  # bilinear: separable two-hot rows
-            xf = jnp.floor(xi)
-            yf = jnp.floor(yi)
-            ok = (xf >= 0) & (xf + 1 <= w - 1) & (yf >= 0) & (yf + 1 <= h - 1)
-            wt = (vv * ok.astype(jnp.float32)).astype(onehot_dtype)
-            fx = (xi - xf).astype(onehot_dtype)
-            fy = (yi - yf).astype(onehot_dtype)
-            ox = ((xf[:, None] == col_x).astype(onehot_dtype) * (1 - fx)[:, None]
-                  + ((xf + 1)[:, None] == col_x).astype(onehot_dtype) * fx[:, None])
-            oy = ((yf[:, None] == col_y).astype(onehot_dtype) * (1 - fy)[:, None]
-                  + ((yf + 1)[:, None] == col_y).astype(onehot_dtype) * fy[:, None])
-            ox = ox * wt[:, None]
-
-        # votes = Oy^T @ Ox on the MXU; int8 operands accumulate in int32
-        # (exact: counts <= E), float in fp32 (exact: counts << 2^24)
-        acc_type = jnp.int32 if onehot_dtype == jnp.int8 else jnp.float32
-        votes = jax.lax.dot_general(
-            oy, ox,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=acc_type,
-        )  # (h_pad, w_pad)
-        acc_ref[p, :, :] += votes.astype(jnp.float32)
+            # votes = Oy @ Ox^T on the MXU, contracting the event (lane)
+            # axis of both; int8 operands accumulate in int32 (exact:
+            # counts <= E), float in fp32 (exact: counts << 2^24)
+            votes = jax.lax.dot_general(
+                oy, ox,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=precision,
+                preferred_element_type=acc_type,
+            )  # (h_pad, w_pad)
+            acc_ref[p, :, :] += votes.astype(jnp.float32)
 
     @pl.when(f == nf - 1)
     def _store_and_detect():
@@ -281,13 +293,15 @@ def backproject_vote_pallas(
         _kernel, cx=cx, cy=cy, w=w, h=h, nz=nz, bz=block_z, fs=fs, nf=nf,
         mode=mode, quantized=quantized, onehot_dtype=onehot_dtype,
     )
+    # events as (F, 1, E): a block's last two dims (1, E) equal the
+    # array's, which Mosaic accepts for any E and any frames_per_step
+    events = [a.reshape(F, 1, E) for a in (x0, y0, valid)]
+    event_spec = pl.BlockSpec((fs, 1, E), lambda z, f: (f, 0, 0))
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((fs, E), lambda z, f: (f, 0)),
-            pl.BlockSpec((fs, E), lambda z, f: (f, 0)),
-            pl.BlockSpec((fs, E), lambda z, f: (f, 0)),
+            event_spec, event_spec, event_spec,
             pl.BlockSpec((fs, block_z, 3), lambda z, f: (f, z, 0)),
         ],
         out_specs=[
@@ -310,4 +324,4 @@ def backproject_vote_pallas(
             pltpu.VMEM((h_pad, w_pad), jnp.float32),  # prev_was_best
         ],
         interpret=resolve_interpret(interpret),
-    )(x0, y0, valid, phi)
+    )(*events, phi)

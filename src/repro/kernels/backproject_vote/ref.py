@@ -42,6 +42,8 @@ def backproject_vote_ref(
     nearest datapath of `pipeline.project_frame`."""
     F, E, _ = xy0.shape
     nz = phi.shape[1]
+    # bilinear weights contract in full f32, as in the kernel
+    precision = jax.lax.Precision.HIGHEST if mode == "bilinear" else None
 
     def frame(dsi, inputs):
         xy, v, ph = inputs
@@ -76,7 +78,7 @@ def backproject_vote_ref(
             oy = ((y0f[..., None] == gy) * (1 - fy)[..., None]
                   + ((y0f + 1)[..., None] == gy) * fy[..., None])
             ox = ox * wt[..., None]
-        votes = jnp.einsum("zeh,zew->zhw", oy, ox)
+        votes = jnp.einsum("zeh,zew->zhw", oy, ox, precision=precision)
         return dsi + votes, None
 
     dsi0 = jnp.zeros((nz, h, w), dtype=jnp.float32)

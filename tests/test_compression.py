@@ -61,7 +61,6 @@ def test_error_feedback_removes_bias():
 
 def test_compressed_psum_single_axis():
     """shard_map over a size-1 axis exercises the wire path end to end."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.compression import compressed_psum
@@ -74,8 +73,8 @@ def test_compressed_psum_single_axis():
     def body(g, r):
         return compressed_psum(g, CompressionState(residual=r), "data")
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     mean, new_state = fn(g, state.residual)
     np.testing.assert_allclose(np.asarray(mean["w"]), np.asarray(g["w"]),
                                atol=np.abs(np.asarray(g["w"])).max() / 127)

@@ -21,9 +21,10 @@ sys.path.insert(0, "src")  # subprocess cwd = repo root
 import dataclasses
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+AUTO = (AxisType.Auto,) * 3
+mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO[:2])
 
 # --- 1. flash decode == reference ---------------------------------------
 from repro.distributed.flash_decode import SeqShard
@@ -109,7 +110,7 @@ assert list(specs) == ["xy", "valid", "frame_valid", "H", "phi"]
 assert specs["frame_valid"].shape == (F_pad,)
 with mesh:
     jax.jit(make_emvs_step(cam, dsi_cfg, mesh)).lower(*specs.values())
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=AUTO)
 specs3 = emvs_input_specs(dsi_cfg, frames=4, events=64, segments=2)
 assert all(s.shape[0] == 2 for s in specs3.values())
 with mesh3:
@@ -149,7 +150,7 @@ import tempfile
 from repro.training import checkpoint as ckpt
 with tempfile.TemporaryDirectory() as d:
     ckpt.save(d, 7, s_shd)
-    mesh2 = jax.make_mesh((2, 2), ("data", "model"))  # "lost" half the devices
+    mesh2 = jax.make_mesh((2, 2), ("data", "model"), axis_types=AUTO[:2])  # "lost" half the devices
     sspec2 = state_specs(cfg2, jax.eval_shape(lambda: state), mesh2,
                          shd.ShardingPlan.for_mesh(mesh2))
     sh2 = jax.tree.map(lambda s: NamedSharding(mesh2, s), sspec2,
