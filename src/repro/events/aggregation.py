@@ -231,22 +231,25 @@ class StreamingAggregator:
         streaming engine's `StreamConfig(hygiene=...)` does) before
         this backstop.
         """
-        t_chunk = np.asarray(chunk.t, np.float32)
-        check_chunk_monotone(t_chunk, self._last_t,
-                             context="StreamingAggregator.push")
-        if t_chunk.shape[0]:
-            self._last_t = float(t_chunk[-1])
-        xy = (undistort_events(self.cam, chunk.xy)
-              if self.cam.has_distortion() else chunk.xy)
-        xy = np.concatenate([self._rem_xy, np.asarray(xy, np.float32)])
-        t = np.concatenate([self._rem_t, np.asarray(chunk.t, np.float32)])
-        valid = np.concatenate([self._rem_valid, np.asarray(chunk.valid, bool)])
-        e = self.events_per_frame
-        n_frames = xy.shape[0] // e
-        n_keep = n_frames * e
-        self._rem_xy, self._rem_t, self._rem_valid = (
-            xy[n_keep:], t[n_keep:], valid[n_keep:])
-        return self._emit(xy[:n_keep], t[:n_keep], valid[:n_keep], n_frames)
+        with jax.profiler.TraceAnnotation("emvs.aggregate"):
+            t_chunk = np.asarray(chunk.t, np.float32)
+            check_chunk_monotone(t_chunk, self._last_t,
+                                 context="StreamingAggregator.push")
+            if t_chunk.shape[0]:
+                self._last_t = float(t_chunk[-1])
+            xy = (undistort_events(self.cam, chunk.xy)
+                  if self.cam.has_distortion() else chunk.xy)
+            xy = np.concatenate([self._rem_xy, np.asarray(xy, np.float32)])
+            t = np.concatenate([self._rem_t, np.asarray(chunk.t, np.float32)])
+            valid = np.concatenate([self._rem_valid,
+                                    np.asarray(chunk.valid, bool)])
+            e = self.events_per_frame
+            n_frames = xy.shape[0] // e
+            n_keep = n_frames * e
+            self._rem_xy, self._rem_t, self._rem_valid = (
+                xy[n_keep:], t[n_keep:], valid[n_keep:])
+            return self._emit(xy[:n_keep], t[:n_keep], valid[:n_keep],
+                              n_frames)
 
     def push_poses(self, chunk: Trajectory) -> EventFrames:
         """Feed one pose chunk to the streamed trajectory; returns the
@@ -255,8 +258,9 @@ class StreamingAggregator:
             raise RuntimeError(
                 "push_poses requires a TrajectoryBuffer pose source; this "
                 "aggregator was built with a fully-known Trajectory oracle")
-        self.traj.push(chunk)
-        return self._release()
+        with jax.profiler.TraceAnnotation("emvs.aggregate"):
+            self.traj.push(chunk)
+            return self._release()
 
     def finalize_poses(self) -> EventFrames:
         """Declare the pose stream complete and release every stalled frame.
@@ -268,8 +272,9 @@ class StreamingAggregator:
             raise RuntimeError(
                 "finalize_poses requires a TrajectoryBuffer pose source; "
                 "a Trajectory oracle is always complete")
-        self._pose_final = True
-        return self._release()
+        with jax.profiler.TraceAnnotation("emvs.aggregate"):
+            self._pose_final = True
+            return self._release()
 
     def flush(self) -> EventFrames:
         """Emit the buffered tail as one padded frame (empty if no tail).
@@ -277,23 +282,27 @@ class StreamingAggregator:
         In pose-gated mode the tail frame joins the stall queue like any
         other frame; the returned EventFrames contain only what the
         current watermark releases (check `stalled_frames` afterwards)."""
-        e = self.events_per_frame
-        n_rem = self._rem_xy.shape[0]
-        if n_rem == 0:
-            return self._release() if self._gated else empty_event_frames(e)
-        # t_mid from the REAL tail events only — the padding exists to fill
-        # the frame shape and must not drag the pose toward the last event
-        t_mid = np.asarray(np.median(self._rem_t), np.float32).reshape(1)
-        pad = e - n_rem
-        xy = np.concatenate(
-            [self._rem_xy, np.full((pad, 2), PARKED_COORD, np.float32)])
-        t = np.concatenate(
-            [self._rem_t, np.full((pad,), self._rem_t[-1], np.float32)])
-        valid = np.concatenate([self._rem_valid, np.zeros((pad,), bool)])
-        self._rem_xy = np.zeros((0, 2), np.float32)
-        self._rem_t = np.zeros((0,), np.float32)
-        self._rem_valid = np.zeros((0,), bool)
-        return self._emit(xy, t, valid, 1, t_mid=t_mid)
+        with jax.profiler.TraceAnnotation("emvs.aggregate"):
+            e = self.events_per_frame
+            n_rem = self._rem_xy.shape[0]
+            if n_rem == 0:
+                if self._gated:
+                    return self._release()
+                return empty_event_frames(e)
+            # t_mid from the REAL tail events only — the padding exists to
+            # fill the frame shape and must not drag the pose toward the
+            # last event
+            t_mid = np.asarray(np.median(self._rem_t), np.float32).reshape(1)
+            pad = e - n_rem
+            xy = np.concatenate(
+                [self._rem_xy, np.full((pad, 2), PARKED_COORD, np.float32)])
+            t = np.concatenate(
+                [self._rem_t, np.full((pad,), self._rem_t[-1], np.float32)])
+            valid = np.concatenate([self._rem_valid, np.zeros((pad,), bool)])
+            self._rem_xy = np.zeros((0, 2), np.float32)
+            self._rem_t = np.zeros((0,), np.float32)
+            self._rem_valid = np.zeros((0,), bool)
+            return self._emit(xy, t, valid, 1, t_mid=t_mid)
 
     def _emit(self, xy: np.ndarray, t: np.ndarray, valid: np.ndarray,
               n_frames: int, t_mid: np.ndarray | None = None) -> EventFrames:
@@ -337,16 +346,12 @@ class StreamingAggregator:
                         f"are buffered — push the missing pose chunks to "
                         f"drain the stall queue before feeding more events")
             return self._release()
-        enforce_pose_span(self._traj_times_host, t_mid,
-                          self.pose_extrapolation, context="frame mid-times")
-        poses = pose_at_times(self.traj, t_mid)
-        return EventFrames(
-            xy=xy_f,
-            valid=valid_f,
-            t_mid=t_mid,
-            poses=SE3(np.asarray(poses.R, np.float32),
-                      np.asarray(poses.t, np.float32)),
-        )
+        with jax.profiler.TraceAnnotation("emvs.pose_interp"):
+            enforce_pose_span(self._traj_times_host, t_mid,
+                              self.pose_extrapolation,
+                              context="frame mid-times")
+            poses = _host_poses(pose_at_times(self.traj, t_mid))
+        return EventFrames(xy=xy_f, valid=valid_f, t_mid=t_mid, poses=poses)
 
     def _release(self) -> EventFrames:
         """Pose and emit the FIFO prefix of stalled frames the watermark
@@ -376,27 +381,35 @@ class StreamingAggregator:
             return empty_event_frames(e)
         frames = [self._stalled.popleft() for _ in range(take)]
         t_mid = np.asarray([f.t_mid for f in frames], np.float32)
-        times = buf.times
-        n_s = times.shape[0]
-        enforce_pose_span(times, t_mid, self.pose_extrapolation,
-                          context="stalled frame mid-times")
-        # stage only the bracketing slice of the pose history: released
-        # t_mid are ascending (FIFO over a sorted event stream), and
-        # searchsorted over a slice containing every bracket returns the
-        # same intervals — so the pose stays bitwise identical while an
-        # unbounded stream no longer re-transfers its whole past
-        lo = int(np.clip(np.searchsorted(times, t_mid[0], side="right") - 1,
-                         0, n_s - 2))
-        hi = max(min(n_s, int(np.searchsorted(times, t_mid[-1],
-                                              side="right")) + 1), lo + 2)
-        poses = pose_at_times(buf.trajectory(lo, hi), t_mid)
+        with jax.profiler.TraceAnnotation("emvs.pose_interp"):
+            times = buf.times
+            n_s = times.shape[0]
+            enforce_pose_span(times, t_mid, self.pose_extrapolation,
+                              context="stalled frame mid-times")
+            # stage only the bracketing slice of the pose history: released
+            # t_mid are ascending (FIFO over a sorted event stream), and
+            # searchsorted over a slice containing every bracket returns the
+            # same intervals — so the pose stays bitwise identical while an
+            # unbounded stream no longer re-transfers its whole past
+            lo = int(np.clip(np.searchsorted(times, t_mid[0],
+                                             side="right") - 1, 0, n_s - 2))
+            hi = max(min(n_s, int(np.searchsorted(times, t_mid[-1],
+                                                  side="right")) + 1), lo + 2)
+            poses = _host_poses(pose_at_times(buf.trajectory(lo, hi), t_mid))
         return EventFrames(
             xy=np.stack([f.xy for f in frames]),
             valid=np.stack([f.valid for f in frames]),
             t_mid=t_mid,
-            poses=SE3(np.asarray(poses.R, np.float32),
-                      np.asarray(poses.t, np.float32)),
+            poses=poses,
         )
+
+
+def _host_poses(poses: SE3) -> SE3:
+    """Host float32 copies of interpolated poses; the span marks the
+    host's wait for the interpolation's results."""
+    with jax.profiler.TraceAnnotation("emvs.pose_interp.sync"):
+        return SE3(np.asarray(poses.R, np.float32),
+                   np.asarray(poses.t, np.float32))
 
 
 def aggregate(cam: CameraModel, stream: EventStream, traj: Trajectory,

@@ -94,6 +94,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from repro.core.camera import CameraModel
@@ -644,9 +645,10 @@ class MultiStreamEngine:
     def poll(self) -> dict[str, list[SegmentResult]]:
         """Pump the shared dispatcher once; returns each session's newly
         ready results keyed by session id (possibly empty lists)."""
-        self.dispatcher.pump()
-        return {sid: sess._take_fresh()
-                for sid, sess in self._sessions.items()}
+        with jax.profiler.TraceAnnotation("emvs.poll"):
+            self.dispatcher.pump()
+            return {sid: sess._take_fresh()
+                    for sid, sess in self._sessions.items()}
 
     def flush(self, session_id: str | None = None):
         """Flush one session (returns its `EMVSResult`) or, with no id,

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from collections import deque
 
+import jax
 import numpy as np
 
 from repro.core.geometry import SE3
@@ -258,20 +259,23 @@ class StreamSession:
             raise RuntimeError(
                 "push after flush: the event tail was already emitted "
                 "(only push_poses / finalize_poses / flush may follow)")
-        n = self._validate_chunk(chunk)
-        chunk = self.hygiene.scrub(chunk)
-        self.stats["chunks"] += 1
-        if n == 0:
-            # a legal no-op (e.g. a quiet sensor interval), but an easy
-            # symptom of a broken feed — counted so callers can notice
-            self.stats["empty_chunks"] += 1
-        try:
-            self._ingest(self.aggregator.push(chunk))
-        finally:
-            # runs on the PoseStallError (max-stall bound) path too, so
-            # max_stalled records the true peak, not the last quiet push
-            self._track_stall()
-        return self.poll()
+        with jax.profiler.TraceAnnotation("emvs.push"):
+            with jax.profiler.TraceAnnotation("emvs.hygiene"):
+                n = self._validate_chunk(chunk)
+                chunk = self.hygiene.scrub(chunk)
+            self.stats["chunks"] += 1
+            if n == 0:
+                # a legal no-op (e.g. a quiet sensor interval), but an
+                # easy symptom of a broken feed — counted so callers can
+                # notice
+                self.stats["empty_chunks"] += 1
+            try:
+                self._ingest(self.aggregator.push(chunk))
+            finally:
+                # runs on the PoseStallError (max-stall bound) path too, so
+                # max_stalled records the true peak, not the last quiet push
+                self._track_stall()
+            return self.poll()
 
     def push_poses(self, chunk: Trajectory) -> list[SegmentResult]:
         """Feed one pose chunk from the tracker; stalled frames the
@@ -317,36 +321,40 @@ class StreamSession:
 
     def _ingest(self, frames: EventFrames, *,
                 blocking: bool | None = None) -> None:
-        n = int(frames.xy.shape[0])
-        if n == 0:
-            return
-        self.stats["frames"] += n
-        if self._budget is None:
-            self._store.extend(frames)
-            self._sync_store_stats()
-            closed: list[tuple[int, int]] = []
-            t_host = np.asarray(frames.poses.t)
+        """Plan aggregated frames into segments and hand the closed ones
+        to the dispatcher; the spans of the closing `pump()` nest in
+        `emvs.plan`."""
+        with jax.profiler.TraceAnnotation("emvs.plan"):
+            n = int(frames.xy.shape[0])
+            if n == 0:
+                return
+            self.stats["frames"] += n
+            if self._budget is None:
+                self._store.extend(frames)
+                self._sync_store_stats()
+                closed: list[tuple[int, int]] = []
+                t_host = np.asarray(frames.poses.t)
+                for k in range(n):
+                    seg = self.planner.push(t_host[k])
+                    if seg is not None:
+                        closed.append(seg)
+                if closed:
+                    self.dispatcher.enqueue(self, closed)
+                self.dispatcher.pump()
+                return
+            # budgeted admission: frames queue in the backlog and enter the
+            # store one at a time, each admitted only once it fits under the
+            # budget — live_bytes can never exceed it
+            xy = np.asarray(frames.xy)
+            valid = np.asarray(frames.valid)
+            t_mid = np.asarray(frames.t_mid)
+            r = np.asarray(frames.poses.R)
+            t = np.asarray(frames.poses.t)
             for k in range(n):
-                seg = self.planner.push(t_host[k])
-                if seg is not None:
-                    closed.append(seg)
-            if closed:
-                self.dispatcher.enqueue(self, closed)
-            self.dispatcher.pump()
-            return
-        # budgeted admission: frames queue in the backlog and enter the
-        # store one at a time, each admitted only once it fits under the
-        # budget — live_bytes can never exceed it
-        xy = np.asarray(frames.xy)
-        valid = np.asarray(frames.valid)
-        t_mid = np.asarray(frames.t_mid)
-        r = np.asarray(frames.poses.R)
-        t = np.asarray(frames.poses.t)
-        for k in range(n):
-            self._backlog.append((xy[k], valid[k], t_mid[k], r[k], t[k]))
-        if blocking is None:
-            blocking = self._budget_policy == "stall"
-        self._drain_backlog(blocking=blocking, raise_on_full=True)
+                self._backlog.append((xy[k], valid[k], t_mid[k], r[k], t[k]))
+            if blocking is None:
+                blocking = self._budget_policy == "stall"
+            self._drain_backlog(blocking=blocking, raise_on_full=True)
 
     def _drain_backlog(self, *, blocking: bool, raise_on_full: bool) -> None:
         """Admit backlogged frames into the store under the byte budget.
@@ -418,10 +426,11 @@ class StreamSession:
         adaptive policy was holding. Under a memory budget, frames a
         rejected push left in the admission backlog retry admission here
         (non-blocking, never raising) as completed sweeps free bytes."""
-        if self._backlog:
-            self._drain_backlog(blocking=False, raise_on_full=False)
-        self.dispatcher.pump()
-        return self._take_fresh()
+        with jax.profiler.TraceAnnotation("emvs.poll"):
+            if self._backlog:
+                self._drain_backlog(blocking=False, raise_on_full=False)
+            self.dispatcher.pump()
+            return self._take_fresh()
 
     def flush(self) -> EMVSResult:
         """End of this session's stream: flush the partial frame and the

@@ -233,7 +233,13 @@ class SweepDispatcher:
         # policy's decisions (0 unless target_latency_s + a cost model
         # are both active); queue_wait_s / sweep_time_s are _LatencyHist
         # snapshots (enqueue->dispatch per segment, dispatch->harvest
-        # per sweep) refreshed on every observation.
+        # per sweep) refreshed on every observation. sweep_time_s is
+        # wall time on the host clock: it includes queueing behind older
+        # sweeps and the lag until a poll harvests; the sweep's device
+        # time comes from a profiler trace. backpressure_harvests counts
+        # the harvests that blocked because every in-flight slot was
+        # full (in _dispatch and make_room), i.e. dispatches that stalled
+        # the pushing client.
         self._queue_wait_hist = _LatencyHist()
         self._sweep_time_hist = _LatencyHist()
         self._session_wait_hists: dict[int, _LatencyHist] = {}
@@ -243,6 +249,7 @@ class SweepDispatcher:
                       "coalesced_dispatches": 0, "coalesced_segments": 0,
                       "cross_stream_dispatches": 0,
                       "slo_dispatches": 0, "slo_holds": 0,
+                      "backpressure_harvests": 0,
                       "queue_wait_s": self._queue_wait_hist.snapshot(),
                       "sweep_time_s": self._sweep_time_hist.snapshot()}
 
@@ -342,7 +349,7 @@ class SweepDispatcher:
                 # back-pressure on the oldest in-flight sweep
                 if not blocking:
                     return False
-                self._harvest(self._inflight.popleft(), block=True)
+                self._block_for_slot()
             group = self._pop_group(final=True, only=session)
             if group is None:
                 return False
@@ -531,48 +538,60 @@ class SweepDispatcher:
         # empty dispatch is a planner/grouping bug, not a stream condition
         # — and pad_segment_rows would reject it anyway.
         assert group, "_dispatch requires at least one closed segment"
-        s_pad = self._s_bucket(len(group))
-        # padded rows repeat the last real segment: the sweep body is
-        # per-segment independent, so they are pure discarded work
-        padded = list(group) + [group[-1]] * (s_pad - len(group))
-        rows = [(sess._store.window(start, end), (0, end - start))
-                for sess, (start, end) in padded]
-        batch = pad_segment_rows(rows, cap)
-        # async dispatch: both calls below return with the sweep enqueued,
-        # so the caller stages the next batch while this one votes
-        unshadowed = not self._inflight  # nothing older occupies the device
-        t_disp = perf_counter()
-        key = self._variant_key(s_pad, cap)
-        for sess, seg in group:
-            t_enq = self._enqueued_t.pop((id(sess), seg), None)
-            if t_enq is not None:
-                self._queue_wait_hist.observe(t_enq, t_disp)
-                sess_hist = self._session_wait_hists.get(id(sess))
-                if sess_hist is not None:
-                    sess_hist.observe(t_enq, t_disp)
-                    sess.stats["queue_wait_s"] = sess_hist.snapshot()
-        self.stats["queue_wait_s"] = self._queue_wait_hist.snapshot()
-        if self.profiler is not None:
-            self.profiler.note_dispatch(t_disp, group, key)
-        dsis, dms = self._sweep(batch)
-        pcs = depth_maps_to_points(self.cam, dms, SE3(batch.ref_R, batch.ref_t))
-        self._inflight.append(_InFlight(
-            [seg for _, seg in group], batch.ref_R, batch.ref_t, dsis, dms,
-            pcs, owners=tuple(sess for sess, _ in group), key=key,
-            dispatched_t=t_disp, unshadowed=unshadowed))
-        self.stats["segments"] += len(group)
-        self.stats["dispatches"] += 1
-        self.stats["padded_segments"] += s_pad - len(group)
-        if len(group) > 1:
-            self.stats["coalesced_dispatches"] += 1
-            self.stats["coalesced_segments"] += len(group)
-        if len({id(sess) for sess, _ in group}) > 1:
-            self.stats["cross_stream_dispatches"] += 1
-        for sess, _ in group:
-            sess.stats["segments"] += 1
-        while len(self._inflight) > self.stream_cfg.max_inflight:
-            # back-pressure: block on the oldest sweep; its results are
-            # routed for the owning sessions' next poll
+        with jax.profiler.TraceAnnotation("emvs.dispatch"):
+            s_pad = self._s_bucket(len(group))
+            # padded rows repeat the last real segment: the sweep body is
+            # per-segment independent, so they are pure discarded work
+            padded = list(group) + [group[-1]] * (s_pad - len(group))
+            with jax.profiler.TraceAnnotation("emvs.stage"):
+                rows = [(sess._store.window(start, end), (0, end - start))
+                        for sess, (start, end) in padded]
+                batch = pad_segment_rows(rows, cap)
+            # async dispatch: both calls below return with the sweep
+            # enqueued, so the caller stages the next batch while this
+            # one votes
+            unshadowed = not self._inflight  # nothing older on the device
+            t_disp = perf_counter()
+            key = self._variant_key(s_pad, cap)
+            for sess, seg in group:
+                t_enq = self._enqueued_t.pop((id(sess), seg), None)
+                if t_enq is not None:
+                    self._queue_wait_hist.observe(t_enq, t_disp)
+                    sess_hist = self._session_wait_hists.get(id(sess))
+                    if sess_hist is not None:
+                        sess_hist.observe(t_enq, t_disp)
+                        sess.stats["queue_wait_s"] = sess_hist.snapshot()
+            self.stats["queue_wait_s"] = self._queue_wait_hist.snapshot()
+            if self.profiler is not None:
+                self.profiler.note_dispatch(t_disp, group, key)
+            with jax.profiler.TraceAnnotation("emvs.launch"):
+                dsis, dms = self._sweep(batch)
+                pcs = depth_maps_to_points(self.cam, dms,
+                                           SE3(batch.ref_R, batch.ref_t))
+            self._inflight.append(_InFlight(
+                [seg for _, seg in group], batch.ref_R, batch.ref_t, dsis,
+                dms, pcs, owners=tuple(sess for sess, _ in group), key=key,
+                dispatched_t=t_disp, unshadowed=unshadowed))
+            self.stats["segments"] += len(group)
+            self.stats["dispatches"] += 1
+            self.stats["padded_segments"] += s_pad - len(group)
+            if len(group) > 1:
+                self.stats["coalesced_dispatches"] += 1
+                self.stats["coalesced_segments"] += len(group)
+            if len({id(sess) for sess, _ in group}) > 1:
+                self.stats["cross_stream_dispatches"] += 1
+            for sess, _ in group:
+                sess.stats["segments"] += 1
+            while len(self._inflight) > self.stream_cfg.max_inflight:
+                # back-pressure: block on the oldest sweep; its results
+                # are routed for the owning sessions' next poll
+                self._block_for_slot()
+
+    def _block_for_slot(self) -> None:
+        """Back-pressure: every in-flight slot is taken, so block on the
+        oldest sweep and harvest it."""
+        with jax.profiler.TraceAnnotation("emvs.backpressure"):
+            self.stats["backpressure_harvests"] += 1
             self._harvest(self._inflight.popleft(), block=True)
 
     # --- harvest ----------------------------------------------------------
@@ -584,33 +603,38 @@ class SweepDispatcher:
             self._harvest(self._inflight.popleft(), block=False)
 
     def _harvest(self, inf: _InFlight, block: bool) -> None:
-        if block:
-            inf.dms.depth.block_until_ready()
-        t_harv = perf_counter()
-        if inf.key is not None:
-            self._sweep_time_hist.observe(inf.dispatched_t, t_harv)
-            self.stats["sweep_time_s"] = self._sweep_time_hist.snapshot()
-            if self.profiler is not None:
-                self.profiler.note_harvest(
-                    inf.key, inf.dispatched_t, t_harv,
-                    unshadowed=inf.unshadowed)
-        owners = inf.owners
-        if owners is None:
-            owners = (self.default_owner,) * len(inf.segs)
-        for k, ((start, end), sess) in enumerate(zip(inf.segs, owners)):
-            # per-segment fraction of DSI voxels at the int16 store limits,
-            # feeding the owning session's "dsi_saturation_peak" monitor
-            # (the live check of the paper's "16 bits never saturate"
-            # claim). Computed on results that are already device-complete,
-            # so this adds one tiny reduction, not a per-chunk round-trip.
-            sat = float(dsi_lib.store_saturation_fraction(inf.dsis[k]))
-            sess.stats["dsi_saturation_peak"] = max(
-                sess.stats.get("dsi_saturation_peak", 0.0), sat)
-            dm = DepthMap(inf.dms.depth[k], inf.dms.mask[k],
-                          inf.dms.confidence[k])
-            res = SegmentResult(dm, inf.dsis[k],
-                                SE3(inf.ref_R[k], inf.ref_t[k]), (start, end))
-            pc = PointCloud(inf.pcs.points[k], inf.pcs.weights[k],
-                            inf.pcs.valid[k])
-            sess._done[(start, end)] = (res, pc)
-            sess._fresh.append(res)
+        with jax.profiler.TraceAnnotation("emvs.harvest"):
+            with jax.profiler.TraceAnnotation("emvs.harvest.sync"):
+                if block:
+                    inf.dms.depth.block_until_ready()
+                t_harv = perf_counter()
+                # per-segment fraction of DSI voxels at the int16 store
+                # limits, feeding the owning session's
+                # "dsi_saturation_peak" monitor (the live check of the
+                # paper's "16 bits never saturate" claim). Computed on
+                # results that are already device-complete, so this adds
+                # one tiny reduction, not a per-chunk round-trip.
+                sats = [float(dsi_lib.store_saturation_fraction(inf.dsis[k]))
+                        for k in range(len(inf.segs))]
+            if inf.key is not None:
+                self._sweep_time_hist.observe(inf.dispatched_t, t_harv)
+                self.stats["sweep_time_s"] = self._sweep_time_hist.snapshot()
+                if self.profiler is not None:
+                    self.profiler.note_harvest(
+                        inf.key, inf.dispatched_t, t_harv,
+                        unshadowed=inf.unshadowed)
+            owners = inf.owners
+            if owners is None:
+                owners = (self.default_owner,) * len(inf.segs)
+            for k, ((start, end), sess) in enumerate(zip(inf.segs, owners)):
+                sess.stats["dsi_saturation_peak"] = max(
+                    sess.stats.get("dsi_saturation_peak", 0.0), sats[k])
+                dm = DepthMap(inf.dms.depth[k], inf.dms.mask[k],
+                              inf.dms.confidence[k])
+                res = SegmentResult(dm, inf.dsis[k],
+                                    SE3(inf.ref_R[k], inf.ref_t[k]),
+                                    (start, end))
+                pc = PointCloud(inf.pcs.points[k], inf.pcs.weights[k],
+                                inf.pcs.valid[k])
+                sess._done[(start, end)] = (res, pc)
+                sess._fresh.append(res)
