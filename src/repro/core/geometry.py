@@ -37,6 +37,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.camera import CameraModel
 
@@ -98,28 +99,40 @@ def so3_exp(w: Array) -> Array:
     return jnp.where(theta < 1e-8, eye, R)
 
 
-def interpolate_pose(p0: SE3, p1: SE3, frac: Array) -> SE3:
+def interpolate_pose(p0: SE3, p1: SE3, frac) -> SE3:
     """Linear pose interpolation (translation lerp; rotation via axis-angle).
 
     Used to assign a camera pose to each event timestamp between two
     trajectory samples (events are asynchronous; poses are sampled).
     For the small inter-sample motions of event cameras this matches the
     first-order interpolation used by the EMVS reference implementation.
+
+    Host float32 NumPy, batched over leading dims (`frac` has the poses'
+    batch shape): the inputs are host pose samples and the results go to
+    the host, so a device round trip per frame batch would be pure
+    dispatch cost. The 3x3 products are written out elementwise, so a
+    pose is bit-identical whatever batch it is interpolated in.
     """
-    t = p0.t + frac * (p1.t - p0.t)
+    f32 = np.float32
+    R0, R1 = np.asarray(p0.R, f32), np.asarray(p1.R, f32)
+    t0, t1 = np.asarray(p0.t, f32), np.asarray(p1.t, f32)
+    frac = np.asarray(frac, f32)[..., None]
+    t = t0 + frac * (t1 - t0)
     # relative rotation
-    dR = _mm(p1.R, jnp.swapaxes(p0.R, -1, -2))
+    dR = _mm3(R1, np.swapaxes(R0, -1, -2))
     w = so3_log(dR)
-    R = _mm(so3_exp(w * frac), p0.R)
+    R = _mm3(_so3_exp_host(w * frac), R0)
     return SE3(R, t)
 
 
-def so3_log(R: Array) -> Array:
-    """Rotation matrix -> axis-angle (..., 3)."""
-    cos_theta = jnp.clip((jnp.trace(R, axis1=-2, axis2=-1) - 1.0) / 2.0, -1.0, 1.0)
-    theta = jnp.arccos(cos_theta)
-    sin_theta = jnp.sin(theta)
-    v = jnp.stack(
+def so3_log(R) -> np.ndarray:
+    """Rotation matrix -> axis-angle (..., 3), host float32."""
+    R = np.asarray(R, np.float32)
+    trace = R[..., 0, 0] + R[..., 1, 1] + R[..., 2, 2]
+    cos_theta = np.clip((trace - 1.0) / 2.0, -1.0, 1.0)
+    theta = np.arccos(cos_theta)
+    sin_theta = np.sin(theta)
+    v = np.stack(
         [
             R[..., 2, 1] - R[..., 1, 2],
             R[..., 0, 2] - R[..., 2, 0],
@@ -127,8 +140,36 @@ def so3_log(R: Array) -> Array:
         ],
         axis=-1,
     )
-    scale = jnp.where(jnp.abs(sin_theta) < 1e-8, 0.5, theta / (2.0 * sin_theta + 1e-30))
+    scale = np.where(np.abs(sin_theta) < 1e-8, np.float32(0.5),
+                     theta / (2.0 * sin_theta + 1e-30))
     return v * scale[..., None]
+
+
+def _so3_exp_host(w: np.ndarray) -> np.ndarray:
+    """Rodrigues on the host: `so3_exp`'s formula in float32 NumPy."""
+    wx, wy, wz = w[..., 0], w[..., 1], w[..., 2]
+    theta = np.sqrt(wx * wx + wy * wy + wz * wz)[..., None, None]
+    safe = np.where(theta < 1e-8, np.float32(1.0), theta)
+    zeros = np.zeros_like(wx)
+    K = np.stack(
+        [
+            np.stack([zeros, -wz, wy], axis=-1),
+            np.stack([wz, zeros, -wx], axis=-1),
+            np.stack([-wy, wx, zeros], axis=-1),
+        ],
+        axis=-2,
+    ) / safe
+    eye = np.broadcast_to(np.eye(3, dtype=np.float32), K.shape)
+    R = eye + np.sin(theta) * K + (1.0 - np.cos(theta)) * _mm3(K, K)
+    return np.where(theta < 1e-8, eye, R)
+
+
+def _mm3(A: np.ndarray, B: np.ndarray) -> np.ndarray:
+    """Batched 3x3 product as three rank-1 terms added in a fixed order
+    (no BLAS), so each product is the same bits in any batch."""
+    return (A[..., :, 0, None] * B[..., None, 0, :]
+            + A[..., :, 1, None] * B[..., None, 1, :]
+            + A[..., :, 2, None] * B[..., None, 2, :])
 
 
 # ---------------------------------------------------------------------------

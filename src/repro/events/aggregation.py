@@ -170,7 +170,6 @@ class StreamingAggregator:
                 f"max_stalled must be >= 1 (or None for unbounded), got "
                 f"{max_stalled}")
         self.cam = cam
-        self.traj = traj
         self.pose_extrapolation = pose_extrapolation
         self.max_stalled = max_stalled
         self._gated = isinstance(traj, TrajectoryBuffer)
@@ -179,9 +178,13 @@ class StreamingAggregator:
                 "max_stalled requires a TrajectoryBuffer pose source: a "
                 "fully-known Trajectory oracle never stalls frames, so "
                 "the bound would silently do nothing")
-        # one host copy of the oracle's sample times for span checks
-        self._traj_times_host = (None if self._gated
-                                 else np.asarray(traj.times, np.float32))
+        # the oracle as one host float32 copy: span checks and pose
+        # interpolation read it on every push, and a device-array
+        # Trajectory read there would wait behind the running sweep
+        self.traj = traj if self._gated else Trajectory(
+            np.asarray(traj.times, np.float32),
+            SE3(np.asarray(traj.poses.R, np.float32),
+                np.asarray(traj.poses.t, np.float32)))
         self.events_per_frame = int(events_per_frame)
         self._rem_xy = np.zeros((0, 2), np.float32)
         self._rem_t = np.zeros((0,), np.float32)
@@ -215,7 +218,7 @@ class StreamingAggregator:
         """Latest safely interpolable pose time received so far."""
         if self._gated:
             return self.traj.watermark
-        return float(self._traj_times_host[-1])
+        return float(self.traj.times[-1])
 
     def push(self, chunk: EventStream) -> EventFrames:
         """Ingest a chunk (sorted, contiguous with prior pushes) of events.
@@ -347,10 +350,10 @@ class StreamingAggregator:
                         f"drain the stall queue before feeding more events")
             return self._release()
         with jax.profiler.TraceAnnotation("emvs.pose_interp"):
-            enforce_pose_span(self._traj_times_host, t_mid,
+            enforce_pose_span(self.traj.times, t_mid,
                               self.pose_extrapolation,
                               context="frame mid-times")
-            poses = _host_poses(pose_at_times(self.traj, t_mid))
+            poses = pose_at_times(self.traj, t_mid)
         return EventFrames(xy=xy_f, valid=valid_f, t_mid=t_mid, poses=poses)
 
     def _release(self) -> EventFrames:
@@ -386,30 +389,22 @@ class StreamingAggregator:
             n_s = times.shape[0]
             enforce_pose_span(times, t_mid, self.pose_extrapolation,
                               context="stalled frame mid-times")
-            # stage only the bracketing slice of the pose history: released
-            # t_mid are ascending (FIFO over a sorted event stream), and
-            # searchsorted over a slice containing every bracket returns the
-            # same intervals — so the pose stays bitwise identical while an
-            # unbounded stream no longer re-transfers its whole past
+            # interpolate over the bracketing slice of the pose history:
+            # released t_mid are ascending (FIFO over a sorted event
+            # stream), and searchsorted over a slice containing every
+            # bracket returns the same intervals, so the pose is bitwise
+            # the one the whole history gives
             lo = int(np.clip(np.searchsorted(times, t_mid[0],
                                              side="right") - 1, 0, n_s - 2))
             hi = max(min(n_s, int(np.searchsorted(times, t_mid[-1],
                                                   side="right")) + 1), lo + 2)
-            poses = _host_poses(pose_at_times(buf.trajectory(lo, hi), t_mid))
+            poses = pose_at_times(buf.trajectory(lo, hi), t_mid)
         return EventFrames(
             xy=np.stack([f.xy for f in frames]),
             valid=np.stack([f.valid for f in frames]),
             t_mid=t_mid,
             poses=poses,
         )
-
-
-def _host_poses(poses: SE3) -> SE3:
-    """Host float32 copies of interpolated poses; the span marks the
-    host's wait for the interpolation's results."""
-    with jax.profiler.TraceAnnotation("emvs.pose_interp.sync"):
-        return SE3(np.asarray(poses.R, np.float32),
-                   np.asarray(poses.t, np.float32))
 
 
 def aggregate(cam: CameraModel, stream: EventStream, traj: Trajectory,

@@ -15,8 +15,8 @@ of silently clamping to a stale, frozen pose (the seed's latent bug:
 front got the last pose with no error and back-projected quietly
 wrong).
 
-`pose_at_times` (the interpolation core, re-exported by
-`repro.events.aggregation` for compatibility) lives here too, with the
+`pose_at_times` (the interpolation core, host float32 NumPy,
+re-exported by `repro.events.aggregation`) lives here too, with the
 `strict=` mode and the single-sample validation; `enforce_pose_span`
 is the shared out-of-span policy ("clamp" — the seed behavior, opt-in
 only — / "warn" / "raise") used by the offline aggregation path and by
@@ -26,14 +26,10 @@ from __future__ import annotations
 
 import warnings
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.geometry import SE3, interpolate_pose
 from repro.events.simulator import Trajectory
-
-Array = jax.Array
 
 # Out-of-span pose-query policies: "clamp" silently freezes the pose at
 # the nearest trajectory endpoint (the seed behavior, kept only behind
@@ -84,9 +80,15 @@ def enforce_pose_span(times: np.ndarray, t_query, policy: str,
     warnings.warn(msg, PoseExtrapolationWarning, stacklevel=2)
 
 
-def pose_at_times(traj: Trajectory, t_query: Array, *,
+def pose_at_times(traj: Trajectory, t_query, *,
                   strict: bool = False) -> SE3:
     """Interpolate trajectory poses at query times (vectorized).
+
+    Host float32 NumPy end to end: the samples are read as host arrays
+    and the poses come back as host `np.float32` arrays, so no device
+    program runs. A device-array `Trajectory` costs a copy to the host
+    on every call; callers on a hot path keep a host copy (the
+    aggregator does).
 
     With `strict=True`, queries outside `[times[0], times[-1]]` raise
     `PoseExtrapolationError` (host-side check) instead of clamping to the
@@ -103,25 +105,18 @@ def pose_at_times(traj: Trajectory, t_query: Array, *,
         raise ValueError(
             f"pose interpolation needs at least 2 trajectory samples, got "
             f"{n}: one sample cannot bracket any query time")
+    times = np.asarray(traj.times, np.float32)
+    t_query = np.asarray(t_query, np.float32)
     if strict:
-        enforce_pose_span(np.asarray(traj.times), t_query, "raise")
-    # stage the samples (host callers — TrajectoryBuffer, the aggregator —
-    # hold numpy; the vmapped gather below needs device arrays)
-    times = jnp.asarray(traj.times)
-    R, t = jnp.asarray(traj.poses.R), jnp.asarray(traj.poses.t)
+        enforce_pose_span(times, t_query, "raise")
+    R = np.asarray(traj.poses.R, np.float32)
+    t = np.asarray(traj.poses.t, np.float32)
     # locate bracketing samples
-    idx = jnp.clip(jnp.searchsorted(times, t_query, side="right") - 1,
-                   0, n - 2)
+    idx = np.clip(np.searchsorted(times, t_query, side="right") - 1, 0, n - 2)
     t0, t1 = times[idx], times[idx + 1]
-    frac = jnp.clip((t_query - t0) / jnp.maximum(t1 - t0, 1e-9), 0.0, 1.0)
-
-    def interp_one(i, f):
-        p0 = SE3(R[i], t[i])
-        p1 = SE3(R[i + 1], t[i + 1])
-        return interpolate_pose(p0, p1, f)
-
-    poses = jax.vmap(interp_one)(idx, frac)
-    return poses
+    frac = np.clip((t_query - t0) / np.maximum(t1 - t0, 1e-9), 0.0, 1.0)
+    return interpolate_pose(SE3(R[idx], t[idx]), SE3(R[idx + 1], t[idx + 1]),
+                            frac)
 
 
 class TrajectoryBuffer:
@@ -213,12 +208,8 @@ class TrajectoryBuffer:
         return (tq >= self._times[0]) & (tq <= self._times[-1])
 
     def trajectory(self, lo: int = 0, hi: int | None = None) -> Trajectory:
-        """Host-side view of samples [lo, hi) (everything by default).
-
-        Callers that interpolate repeatedly over an unbounded stream
-        should pass the bracketing slice of their queries — staging the
-        whole history to the device on every release would grow
-        quadratically with stream length."""
+        """Host-side view (no copy) of samples [lo, hi), everything by
+        default."""
         sl = slice(lo, hi)
         return Trajectory(times=self._times[sl],
                           poses=SE3(self._R[sl], self._t[sl]))

@@ -115,3 +115,46 @@ def small_scene(cam):
 
 def rng(seed: int = 0) -> np.random.Generator:
     return np.random.default_rng(seed)
+
+
+@pytest.fixture(scope="session")
+def pose_f64():
+    """Frame-pose interpolation in float64 NumPy, written out apart from
+    `repro.core.geometry`: the reference the float32 host path is
+    compared against (`so3_log`, `so3_exp`, `pose_at_times`)."""
+
+    def so3_log(R):
+        R = np.asarray(R, np.float64)
+        cos = np.clip((np.trace(R, axis1=-2, axis2=-1) - 1.0) / 2.0, -1.0, 1.0)
+        theta = np.arccos(cos)
+        sin = np.sin(theta)
+        v = np.stack([R[..., 2, 1] - R[..., 1, 2], R[..., 0, 2] - R[..., 2, 0],
+                      R[..., 1, 0] - R[..., 0, 1]], axis=-1)
+        scale = np.where(np.abs(sin) < 1e-8, 0.5, theta / (2.0 * sin + 1e-30))
+        return v * scale[..., None]
+
+    def so3_exp(w):
+        w = np.asarray(w, np.float64)
+        theta = np.linalg.norm(w, axis=-1)[..., None, None]
+        zero = np.zeros_like(w[..., 0])
+        K = np.stack([np.stack([zero, -w[..., 2], w[..., 1]], axis=-1),
+                      np.stack([w[..., 2], zero, -w[..., 0]], axis=-1),
+                      np.stack([-w[..., 1], w[..., 0], zero], axis=-1)],
+                     axis=-2) / np.where(theta < 1e-8, 1.0, theta)
+        eye = np.broadcast_to(np.eye(3), K.shape)
+        R = eye + np.sin(theta) * K + (1.0 - np.cos(theta)) * (K @ K)
+        return np.where(theta < 1e-8, eye, R)
+
+    def pose_at_times(times, R, t, t_query):
+        times, R, t, tq = (np.asarray(a, np.float64)
+                           for a in (times, R, t, t_query))
+        i = np.clip(np.searchsorted(times, tq, side="right") - 1,
+                    0, times.shape[0] - 2)
+        frac = np.clip((tq - times[i])
+                       / np.maximum(times[i + 1] - times[i], 1e-9), 0.0, 1.0)
+        w = so3_log(R[i + 1] @ np.swapaxes(R[i], -1, -2))
+        return (so3_exp(w * frac[..., None]) @ R[i],
+                t[i] + frac[..., None] * (t[i + 1] - t[i]))
+
+    return types.SimpleNamespace(so3_log=so3_log, so3_exp=so3_exp,
+                                 pose_at_times=pose_at_times)

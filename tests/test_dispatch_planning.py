@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import json
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -63,7 +64,11 @@ from repro.serving.dispatch_replay import (
     planner_for,
     replay_schedule,
 )
-from repro.serving.emvs_stream import EMVSStreamEngine, StreamConfig
+from repro.serving.emvs_stream import (
+    EMVSStreamEngine,
+    StreamConfig,
+    iter_event_chunks,
+)
 from test_segment_batching import _assert_results_match
 
 EVENTS_PER_FRAME = 224
@@ -370,8 +375,6 @@ def planning_scene(cam, small_scene):
 
 
 def _run_burst(engine, ev):
-    from repro.serving.emvs_stream import iter_event_chunks
-
     engine.push(next(iter_event_chunks(ev, int(ev.t.shape[0]))))
     return engine.flush()
 
@@ -437,7 +440,13 @@ def test_profiler_records_trace_and_warm_samples(cam, planning_scene):
         StreamConfig(events_per_frame=EVENTS_PER_FRAME,
                      dispatch_policy="latency"),
         profiler=profiler)
-    _run_burst(engine, ev)
+    # every variant needs one sweep dispatched onto an idle device for
+    # its cold compile to be skipped (checked below): let the burst's
+    # sweeps finish before the flush dispatches the tail's variant
+    engine.push(next(iter_event_chunks(ev, int(ev.t.shape[0]))))
+    for inf in list(engine._inflight):
+        jax.block_until_ready(inf.dms.depth)
+    engine.flush()
     trace = profiler.trace_json()
     arrived = {(a["tag"], tuple(a["seg"])) for a in trace["arrivals"]}
     dispatched = [(tag, tuple(seg)) for d in trace["dispatches"]

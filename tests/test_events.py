@@ -173,6 +173,46 @@ def test_pose_interpolation_monotone(small_scene):
     assert (tx >= lo - 1e-5).all() and (tx <= hi + 1e-5).all()
 
 
+@pytest.mark.parametrize("source", ["oracle", "buffer"])
+def test_posing_frames_makes_no_device_transfer(cam, small_scene, source):
+    """Frame poses are interpolated on the host: once the aggregator
+    holds its pose source (here built from device arrays), no push,
+    pose push, finalize or flush moves data to or from a device, which
+    on a chip would wait behind the running sweep. The frames are the
+    offline oracle's, bit for bit."""
+    from repro.events.aggregation import TrajectoryBuffer, concat_event_frames
+    from repro.events.simulator import slice_trajectory
+
+    traj = jax.tree.map(jnp.asarray, small_scene["traj"])
+    host_traj = jax.tree.map(np.asarray, traj)
+    ev = jax.tree.map(np.asarray, small_scene["events"])
+    n, k = int(ev.t.shape[0]), int(host_traj.times.shape[0]) // 2
+    if source == "oracle":
+        agg = StreamingAggregator(cam, traj, events_per_frame=1024)
+    else:
+        agg = StreamingAggregator(
+            cam, TrajectoryBuffer(slice_trajectory(traj, 0, k)),
+            events_per_frame=1024)
+
+    def part(i, j):
+        return jax.tree.map(lambda x: x[i:j], ev)
+
+    parts = []
+    with jax.transfer_guard("disallow"):
+        parts.append(agg.push(part(0, n // 2)))
+        if source == "buffer":
+            parts.append(agg.push_poses(slice_trajectory(
+                host_traj, k, int(host_traj.times.shape[0]))))
+        parts.append(agg.push(part(n // 2, n)))
+        parts.append(agg.flush())
+        if source == "buffer":
+            parts.append(agg.finalize_poses())
+    got, ref = concat_event_frames(parts), small_scene["frames"]
+    np.testing.assert_array_equal(got.t_mid, np.asarray(ref.t_mid))
+    np.testing.assert_array_equal(got.poses.R, np.asarray(ref.poses.R))
+    np.testing.assert_array_equal(got.poses.t, np.asarray(ref.poses.t))
+
+
 def test_aggregate_pose_extrapolation_policies(cam, small_scene):
     """Offline aggregation no longer freezes out-of-span poses silently:
     the default warns (clamped numerics kept for equivalence), "raise"

@@ -133,7 +133,7 @@ def test_span_tree_of_a_push_that_closes_a_segment(cam, scene, recorder):
                             "emvs.poll"]
     hygiene, aggregate, plan, _ = (k for _, k in kids)
     assert hygiene == []
-    assert aggregate == [("emvs.pose_interp", [("emvs.pose_interp.sync", [])])]
+    assert aggregate == [("emvs.pose_interp", [])]
     assert "emvs.dispatch" in _names(plan)
     assert set(_names(plan)) <= {"emvs.dispatch", "emvs.harvest"}
     for name, kids, path in _walk(roots):
@@ -176,8 +176,7 @@ def test_span_count_per_push_does_not_grow_with_frames(cam, scene, recorder):
     assert dispatches[1] > 0, "the 64-frame push closed no segment"
     assert counts[0] == counts[1] == Counter(
         {"emvs.push": 1, "emvs.hygiene": 1, "emvs.aggregate": 1,
-         "emvs.pose_interp": 1, "emvs.pose_interp.sync": 1, "emvs.plan": 1,
-         "emvs.poll": 1})
+         "emvs.pose_interp": 1, "emvs.plan": 1, "emvs.poll": 1})
 
 
 @pytest.mark.parametrize("fault", ["hygiene", "pose_stall"])

@@ -6,6 +6,8 @@ bit-identically to the offline oracle for any event x pose interleaving.
 """
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -77,6 +79,44 @@ def test_pose_at_times_single_sample_raises():
     empty = _slice(_traj(4), 0, 0)
     with pytest.raises(ValueError, match="at least 2 trajectory samples"):
         pose_at_times(empty, np.asarray([0.0], np.float32))
+
+
+@pytest.mark.parametrize("angle", [0.0, 1e-6, 0.05, 1.0, np.pi - 0.1])
+def test_pose_at_times_host_float32_matches_float64(pose_f64, angle):
+    """Host float32 interpolation against the same formula in float64,
+    for samples `angle` apart in rotation: queries at the sample times,
+    between samples and outside the span (clamped). A device-array
+    trajectory gives the bits of its host copy."""
+    rng = np.random.default_rng(7)
+    n = 12
+    times = np.cumsum(rng.uniform(0.5, 1.5, n)).astype(np.float32)
+    axes = rng.normal(size=(n - 1, 3))
+    axes /= np.linalg.norm(axes, axis=-1, keepdims=True)
+    R = [pose_f64.so3_exp(rng.uniform(-2.0, 2.0, 3))]
+    for axis in axes:
+        R.append(pose_f64.so3_exp(axis * angle) @ R[-1])
+    R = np.stack(R).astype(np.float32)
+    t = rng.normal(size=(n, 3)).astype(np.float32)
+    traj = Trajectory(times=times, poses=SE3(R, t))
+    q = np.concatenate([times, rng.uniform(times[0], times[-1], 40),
+                        [times[0] - 1.0, times[-1] + 1.0]]).astype(np.float32)
+    got = pose_at_times(traj, q)
+    for x in got:
+        assert type(x) is np.ndarray and x.dtype == np.float32
+    R_ref, t_ref = pose_f64.pose_at_times(times, R, t, q)
+    # float32 rounding, amplified near pi by the log's 1 / (1 + cos)
+    tol = 1e-6 + 4 * float(np.finfo(np.float32).eps) / (1.0 + np.cos(angle))
+    np.testing.assert_allclose(got.R, R_ref, rtol=0, atol=tol)
+    np.testing.assert_allclose(got.t, t_ref, rtol=0, atol=1e-5)
+    # a sample time (but the last) and a query below the span read that
+    # sample's pose bit for bit
+    np.testing.assert_array_equal(got.R[:n - 1], R[:n - 1])
+    np.testing.assert_array_equal(got.t[:n - 1], t[:n - 1])
+    np.testing.assert_array_equal(got.R[-2], R[0])
+    np.testing.assert_array_equal(got.t[-2], t[0])
+    dev = pose_at_times(jax.tree.map(jnp.asarray, traj), q)
+    np.testing.assert_array_equal(dev.R, got.R)
+    np.testing.assert_array_equal(dev.t, got.t)
 
 
 def test_enforce_pose_span_policies():
