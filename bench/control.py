@@ -18,6 +18,7 @@ import argparse
 import json
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
@@ -29,22 +30,21 @@ from run import load_cell  # noqa: E402
 
 def control_numbers(config: dict, mix: dict, seed: int, seconds: float) -> dict:
     """Compared numbers of the bfloat16 reference against the float32 one
-    on the segments a run of `seed` would check."""
+    on the segments a run of `seed` would check, as the configuration's
+    driver cuts and votes them."""
+    driver = serve.driver(config)
     setup = reference.Setup.from_config(config)
-    e = setup.events_per_frame
     cameras = make_cameras(config, mix, seed)
     w0 = float(mix["warmup_s"])
-    plan = serve.plan(cameras, mix, setup, w0 + seconds + 2.0)
-    due = [serve.Emitted(cam=i, frames=seg, t_emit=0.0, latency=0.0,
-                         depth=None, mask=None, result=None)
+    plan = driver.plan(cameras, mix, setup, w0 + seconds + 2.0)
+    due = [SimpleNamespace(stream=i, frames=seg)
            for i, (segs, last) in enumerate(zip(plan.segments, plan.last_due))
            for seg, d in zip(segs, last) if w0 <= d < w0 + seconds]
     gaps = {n: 0.0 for n in check.NUMBERS}
     for m in check.sample(due, int(mix["check_segments"]), seed):
-        xy, pos = check.segment_inputs(cameras[m.cam], plan.positions[m.cam],
-                                       m.frames, e)
-        good = check.reference_segment(setup, xy, pos)
-        low = check.reference_segment(setup, xy, pos, lowp=True)
+        inputs = driver.reference_inputs(setup, cameras, plan, m)
+        good = driver.reference(setup, *inputs)
+        low = driver.reference(setup, *inputs, lowp=True)
         for k, v in check.compare(setup, *low, *good).items():
             gaps[k] = max(gaps[k], v)
     return gaps

@@ -28,7 +28,6 @@ class RunView:
 
     window_s: float
     emitted: list  # maps emitted in the window (serve.Emitted)
-    events_per_frame: int
     lags: list[float]  # lateness of each packet due in the window
     spans: list[tuple[str, float, float]]  # client spans inside the window
     stats_open: dict  # dispatcher counters when the window opened
@@ -56,7 +55,6 @@ def make_view(run: dict) -> RunView:
     return RunView(
         window_s=w1 - w0,
         emitted=[m for m in run["emitted"] if w0 <= m.t_emit < w1],
-        events_per_frame=run["setup"].events_per_frame,
         lags=[lag for due, lag in run["lags"] if w0 <= due < w1],
         spans=[(n, max(a, wall[0]), min(b, wall[1])) for n, a, b in run["spans"]
                if b > wall[0] and a < wall[1]],
@@ -97,30 +95,38 @@ def cell_metrics(bench: dict, cell: str, traced: bool) -> list[dict]:
             if cell in m.get("workloads", [cell] if m["moves"] in moved else [])]
 
 
+def _reference(driver_file: str, setup, inputs: tuple):
+    """The reference of one map, in a worker that loads the driver anew."""
+    return serve.load_driver(driver_file).reference(setup, *inputs)
+
+
 def run_cell(bench: dict, cell: str, config: dict, mix: dict, seed: int,
              seconds: float, traced: bool, devices, t_process0: float,
-             work_dir: Path, log=print, peaks: dict | None = None) -> dict:
+             work_dir: Path, log=print, peaks: dict | None = None,
+             drivers: Path = serve.DRIVERS) -> dict:
     """One run; returns the result line's object. `peaks` replaces the
-    table of chip peaks (tests on the host CPU)."""
+    table of chip peaks (tests on the host CPU); `drivers` is where the
+    configuration's driver is found."""
     trace_dir = None
     if traced:
         trace_dir = work_dir / "trace"
         shutil.rmtree(trace_dir, ignore_errors=True)
     run = serve.drive(config, mix, seed, seconds,
                       trace_dir=None if trace_dir is None else str(trace_dir),
-                      devices=devices, t_process0=t_process0, log=log)
+                      devices=devices, t_process0=t_process0, log=log,
+                      drivers=drivers)
     view = make_view(run)
     emitted = view.emitted
-    setup = run["setup"]
-    e = setup.events_per_frame
+    setup, driver = run["setup"], run["driver"]
 
     # correctness: boundaries of every map, and a seeded sample recomputed
     known = [set(s) for s in run["plan"].segments]
-    unmatched = sum(1 for m in run["emitted"] if m.frames not in known[m.cam])
+    unmatched = sum(1 for m in run["emitted"]
+                    if m.frames not in known[m.stream])
     picked = check.sample(emitted, int(mix["check_segments"]), seed)
-    program = [(m, np.asarray(m.result.dsi)) for m in picked]
+    program = [(m, run["served"].dsi(m.result)) for m in picked]
     n_attempted = attempted(run)
-    del run["engine"], run["emitted"]
+    del run["served"], run["emitted"]
     for m in emitted:
         m.result = None
 
@@ -137,16 +143,15 @@ def run_cell(bench: dict, cell: str, config: dict, mix: dict, seed: int,
     t = time.perf_counter()
     gaps = {n: 0.0 for n in check.NUMBERS}
     gaps["segments_unmatched"] = float(unmatched)
-    inputs = [check.segment_inputs(run["cameras"][m.cam],
-                                   run["plan"].positions[m.cam], m.frames, e)
+    inputs = [driver.reference_inputs(setup, run["cameras"], run["plan"], m)
               for m, _ in program]
     # one process per sampled segment: the reference is NumPy on the host
     # and imports nothing that would reach for the chip
     ctx = multiprocessing.get_context("spawn")
     with ProcessPoolExecutor(max(1, min(len(inputs), REFERENCE_WORKERS)),
                              mp_context=ctx) as pool:
-        refs = list(pool.map(check.reference_segment, [setup] * len(inputs),
-                             [xy for xy, _ in inputs], [p for _, p in inputs]))
+        refs = list(pool.map(_reference, [driver.__file__] * len(inputs),
+                             [setup] * len(inputs), inputs))
     for (m, dsi), r in zip(program, refs):
         got = check.compare(setup, dsi, m.depth, m.mask, *r)
         for k, v in got.items():
@@ -164,14 +169,10 @@ def run_cell(bench: dict, cell: str, config: dict, mix: dict, seed: int,
         if kind not in peaks:
             raise KeyError(f"device kind {kind!r} is not in {PEAKS}")
         peak = peaks[kind]
-    d, q = config["dsi"], config["emvs"]["quantized"]
     least = []
     if peak is not None:
         for m in emitted:
-            ops, nbytes = work.segment_work(m.frames[1] - m.frames[0], e,
-                                            d["num_planes"],
-                                            config["sensor"]["height"],
-                                            config["sensor"]["width"], q)
+            ops, nbytes = driver.map_work(config, m)
             least.append(work.least_time_s(ops, nbytes, peak)[0])
     view.trace, view.seg_least_s = reduced, least
 

@@ -1,11 +1,12 @@
 """What decides `correct`: the served results against the plain reference.
 
 Every depth map emitted must close a segment where the reference closes
-one (its frame range is one of the reference's segments of that camera),
+one (its frame range is one of the reference's segments of its stream),
 and a sample of the maps emitted in the window, drawn from the seed with
 the longest among them, is recomputed by the reference from the raw
-traffic and compared: DSI voxel by voxel, the semi-dense mask pixel by
-pixel, and depth where both masks hold. The limits live in the
+traffic (the inputs and the reference that the cell's driver gives)
+and compared: DSI voxel by voxel, the semi-dense mask pixel by pixel,
+and depth where both masks hold. The limits live in the
 configuration's file (`limits`), with the readings they were set from in
 PERF.md.
 """
@@ -30,14 +31,6 @@ def sample(emitted: list, k: int, seed: int) -> list:
     return [emitted[longest]] + [emitted[rest[j]] for j in sorted(pick)]
 
 
-def segment_inputs(camera, positions: np.ndarray, frames: tuple[int, int],
-                   events_per_frame: int):
-    """The reference's inputs for one segment, from the raw traffic."""
-    a, b = frames
-    xy = camera.events(a * events_per_frame, b * events_per_frame)[0]
-    return xy.reshape(b - a, events_per_frame, 2), positions[a:b]
-
-
 def compare(setup: ref.Setup, dsi: np.ndarray, depth: np.ndarray,
             mask: np.ndarray, ref_dsi: np.ndarray, ref_depth: np.ndarray,
             ref_mask: np.ndarray) -> dict:
@@ -50,12 +43,6 @@ def compare(setup: ref.Setup, dsi: np.ndarray, depth: np.ndarray,
     return {"dsi_voxels": float(np.mean(dsi != ref_dsi)),
             "mask_pixels": float(np.sum(mask != ref_mask) / max(1, ref_mask.sum())),
             "depth_gap": gap}
-
-
-def reference_segment(setup: ref.Setup, xy_frames, pos_frames, *, lowp=False):
-    dsi = ref.segment_dsi(setup, xy_frames, pos_frames, lowp=lowp)
-    depth, mask = ref.detect(setup, dsi)
-    return dsi, depth, mask
 
 
 def judge(values: dict, limits: dict) -> bool:

@@ -195,11 +195,14 @@ BLOCKS_PER_COUNT = 8
 
 
 def segment_dsi(setup: Setup, xy_frames: np.ndarray, pos_frames: np.ndarray,
-                *, lowp: bool = False) -> np.ndarray:
+                *, ref_pos: np.ndarray | None = None,
+                lowp: bool = False) -> np.ndarray:
     """DSI (Nz, h, w) int32 of one segment: frames (F, E, 2) of events,
-    camera centres (F, 3); the first frame is the reference view. Frames
-    are projected in blocks and their votes counted per block."""
+    camera centres (F, 3), voted into the reference view centred at
+    `ref_pos` (3,), the first frame's centre where it is None. Frames are
+    projected in blocks and their votes counted per block."""
     p = _Prec(lowp)
+    ref_t = pos_frames[0] if ref_pos is None else np.asarray(ref_pos, F32)
     planes = setup.planes()
     z0 = planes[setup.num_planes // 2]
     nz, h, w = setup.num_planes, setup.height, setup.width
@@ -208,7 +211,7 @@ def segment_dsi(setup: Setup, xy_frames: np.ndarray, pos_frames: np.ndarray,
     votes = []
     for f0 in range(0, xy_frames.shape[0], FRAMES_PER_BLOCK):
         f1 = min(f0 + FRAMES_PER_BLOCK, xy_frames.shape[0])
-        geo = [frame_geometry(setup, pos_frames[0], pos_frames[f], z0, planes, p)
+        geo = [frame_geometry(setup, ref_t, pos_frames[f], z0, planes, p)
                for f in range(f0, f1)]
         H, alpha, bx, by = (np.stack(g) for g in zip(*geo))
         xr, yr = project(setup, xy_frames[f0:f1], H, alpha, bx, by, p)

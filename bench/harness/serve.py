@@ -1,26 +1,55 @@
 """Drives one cell: the served EMVS path under open-loop camera traffic.
 
-One `MultiStreamEngine` serves every camera of the cell. A single
-client thread pushes each camera's packets when they are due
-(`StreamSession.push`) and polls the engine (`MultiStreamEngine.poll`)
-while it waits; a depth map counts as emitted once its `SegmentResult`
-has been returned and its depth and mask are on the host. Set-up
-(traffic, the reference's segmentation, every program the cell's traffic
-uses, and a warm-up stretch of the same traffic) ends when the window
-opens; the window then runs for `seconds` of wall time.
+A driver (`harness/drivers/<name>.py`, named by the configuration's key
+`driver`, `sessions` where it names none) builds the program and binds
+the cell's cameras to it; this module is the part every driver shares.
+A single client thread pushes each camera's packets when they are due
+and polls the program while it waits; a depth map counts as emitted once
+the driver has returned it and its depth and mask are on the host.
+Set-up (traffic, the reference's segmentation, every program the cell's
+traffic uses, and a warm-up stretch of the same traffic) ends when the
+window opens; the window then runs for `seconds` of wall time.
+
+A driver is a module with these functions (`stream` numbers what one
+map is cut from: a camera, or a rig of them):
+
+- `plan(cameras, mix, setup, until_s)`: the reference's view of the
+  traffic before `until_s`, with `segments` (per stream, the closed
+  segments `(first, end)` of frames, in close order) and `last_due` (per
+  stream, the client time of each segment's last event).
+- `serve(config, mix, cameras, plan, until_s, log)`: the program, built,
+  bound to the cameras, and with every program compiled or loaded that
+  the traffic before `until_s` can reach. It has `push(cam, xy, t,
+  polarity, valid)` and `poll()`, each returning `(stream, result)`
+  pairs; `fetch(stream, result)`, the map's `(frames, events, due,
+  depth, mask)`: depth and mask on the host, `events` the events it was
+  built from, `due` the client time of the last of them; `dsi(result)`,
+  its DSI on the host; and `stats()`, the dispatcher's counters (the
+  keys `RunView.delta` reads).
+- `reference_inputs(setup, cameras, plan, m)`: the reference's inputs
+  for the emitted map `m`, from the raw traffic; `reference(setup,
+  *inputs, lowp=False)`: its `(dsi, depth, mask)`, NumPy on the host,
+  with nothing of the program.
+- `map_work(config, m)`: the `(operations, bytes)` the algorithm needs
+  for the map `m` (`harness.work`).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
 import math
+import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
 from harness import reference as ref
-from harness.traffic import Camera, Packetizer, make_cameras
+from harness.traffic import Packetizer, make_cameras
 
+DRIVERS = Path(__file__).resolve().parent / "drivers"
+DEFAULT_DRIVER = "sessions"
 POLL_IDLE_S = 0.002  # poll the engine this often while waiting for packets
 POLL_BUSY_S = 0.005  # ... and at least this often while pushing late ones
 SLEEP_S = 0.0005
@@ -47,13 +76,14 @@ class CompileCounter:
 
 @dataclasses.dataclass
 class Emitted:
-    cam: int
+    stream: int
     frames: tuple[int, int]
+    events: int  # events the map was built from
     t_emit: float  # stream time on the client's clock
     latency: float
     depth: np.ndarray
     mask: np.ndarray
-    result: object  # the SegmentResult (its DSI stays on the device)
+    result: object  # the driver's result (its DSI stays on the device)
 
 
 @dataclasses.dataclass
@@ -76,165 +106,43 @@ class Spans:
         self.rows.append((name, t0, time.perf_counter()))
 
 
-def build_program(config: dict):
-    """The system under test, configured as the cell's file states."""
-    from repro.core.camera import CameraModel
-    from repro.core.dsi import DSIConfig
-    from repro.core.pipeline import EMVSOptions
-    from repro.serving.emvs_stream import MultiStreamEngine, StreamConfig
-
-    s, d, e, st = (config["sensor"], config["dsi"], config["emvs"],
-                   config["stream"])
-    cam = CameraModel(width=s["width"], height=s["height"], fx=s["fx"],
-                      fy=s["fy"], cx=s["cx"], cy=s["cy"])
-    dsi_cfg = DSIConfig.for_camera(cam, num_planes=d["num_planes"],
-                                   z_min=d["z_min"], z_max=d["z_max"],
-                                   inverse_depth=d["inverse_depth"])
-    opts = EMVSOptions(voting=e["voting"], formulation=e["formulation"],
-                       quantized=e["quantized"],
-                       keyframe_dist_frac=e["keyframe_dist_frac"],
-                       detection_threshold_c=e["detection_threshold_c"],
-                       detection_min_votes=e["detection_min_votes"],
-                       median_filter=e["median_filter"])
-    if st["sweep"] != "batched":
-        raise ValueError(f"the benchmark drives the batched sweep, not "
-                         f"{st['sweep']!r}")
-    stream_cfg = StreamConfig(events_per_frame=st["events_per_frame"],
-                              segment_buckets=tuple(st["segment_buckets"]),
-                              max_inflight=st["max_inflight"],
-                              dispatch_policy=st["dispatch_policy"],
-                              hygiene=st["hygiene"], sweep=st["sweep"])
-    engine = MultiStreamEngine(cam, dsi_cfg, opts, stream_cfg)
-    return cam, dsi_cfg, opts, engine
+def driver(config: dict, drivers: Path = DRIVERS):
+    """The driver the configuration names: `<drivers>/<name>.py`."""
+    name = config.get("driver", DEFAULT_DRIVER)
+    path = Path(drivers) / f"{name}.py"
+    if not (name.isidentifier() and path.is_file()):
+        have = sorted(f.name for f in Path(drivers).glob("*.py"))
+        sys.exit(f"unknown driver {name!r}: {drivers} holds {have}")
+    return load_driver(path)
 
 
-@dataclasses.dataclass
-class Plan:
-    """The reference's view of each camera's stream over the run."""
-
-    positions: list[np.ndarray]  # per camera: frame centres (F, 3) float32
-    segments: list[list[tuple[int, int]]]  # per camera: closed segments
-    last_due: list[np.ndarray]  # per camera: client time of each segment's last event
-
-
-def plan(cameras: list[Camera], mix: dict, setup: ref.Setup,
-         until_s: float) -> Plan:
-    e = setup.events_per_frame
-    positions, segments, last_due = [], [], []
-    for cam in cameras:
-        first = np.arange(cam.index_at(until_s) // e) * e
-        # a frame's events are in time order, so its median timestamp is
-        # the mean of its two middle ones
-        mid = ref.middle_mean(cam.times_at(first + e // 2 - 1),
-                              cam.times_at(first + e // 2))
-        times, _, pos = cam.pose_table(mix, until_s + 1.0)
-        p = ref.interpolate_positions(times, pos, mid)
-        segs = ref.key_frame_segments(p, setup)
-        positions.append(p)
-        segments.append(segs)
-        ends = np.array([b for _, b in segs], np.int64)
-        last_due.append(cam.start + cam.times_at(ends * e - 1).astype(np.float64))
-    return Plan(positions, segments, last_due)
-
-
-def capacity(frames: int) -> int:
-    """The served path's frame-capacity bucket (multiples of 4)."""
-    return max(4, -(-frames // 4) * 4)
-
-
-def frames_per_push(cameras: list[Camera], mix: dict, events_per_frame: int,
-                    until_s: float) -> set[int]:
-    """How many frames the pushes before `until_s` complete, as a set."""
-    out = set()
-    for i, cam in enumerate(cameras):
-        pk = Packetizer(i, cam, mix)
-        p = pk.next()
-        while p.due < until_s:
-            out.add(p.g1 // events_per_frame - p.g0 // events_per_frame)
-            p = pk.next()
-    return out - {0}
-
-
-def warm_programs(cam, dsi_cfg, opts, s_buckets, caps, events_per_frame,
-                  traj_len: int, push_frames) -> None:
-    """Compile (or load) every program the window's traffic can reach:
-    each (S bucket, capacity) sweep with its point cloud and harvest
-    slices, and the pose interpolation for each number of frames a push
-    completes."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import dsi as dsi_lib
-    from repro.core.geometry import SE3
-    from repro.core.pipeline import SegmentBatch, process_segments_batched
-    from repro.core.pointcloud import depth_maps_to_points
-    from repro.events.simulator import Trajectory
-    from repro.events.trajectory_stream import pose_at_times
-
-    for s in s_buckets:
-        for c in sorted(caps):
-            f32 = np.float32
-            eye = np.broadcast_to(np.eye(3, dtype=f32), (s, c, 3, 3))
-            batch = SegmentBatch(
-                xy=jnp.asarray(np.zeros((s, c, events_per_frame, 2), f32)),
-                valid=jnp.asarray(np.zeros((s, c, events_per_frame), f32)),
-                frame_valid=jnp.asarray(np.zeros((s, c), f32)),
-                poses_R=jnp.asarray(np.ascontiguousarray(eye)),
-                poses_t=jnp.asarray(np.zeros((s, c, 3), f32)),
-                ref_R=jnp.asarray(np.ascontiguousarray(eye[:, 0])),
-                ref_t=jnp.asarray(np.zeros((s, 3), f32)))
-            dsis, dms = process_segments_batched(cam, dsi_cfg, batch, opts)
-            pcs = depth_maps_to_points(cam, dms, SE3(batch.ref_R, batch.ref_t))
-            dms.depth.is_ready()
-            for k in range(s):
-                float(dsi_lib.store_saturation_fraction(dsis[k]))
-                jax.block_until_ready((dms.depth[k], dms.mask[k],
-                                       dms.confidence[k], dsis[k],
-                                       batch.ref_R[k], batch.ref_t[k],
-                                       pcs.points[k], pcs.weights[k],
-                                       pcs.valid[k]))
-            del dsis, dms, pcs, batch
-    times = jnp.asarray(np.arange(traj_len, dtype=np.float32))
-    traj = Trajectory(times, SE3(jnp.asarray(np.broadcast_to(
-        np.eye(3, dtype=np.float32), (traj_len, 3, 3)).copy()),
-        jnp.zeros((traj_len, 3), jnp.float32)))
-    for n in sorted(push_frames):
-        jax.block_until_ready(pose_at_times(traj, np.linspace(
-            0.5, 1.5, n).astype(np.float32)))
+def load_driver(path):
+    """The driver module in the file `path`, loaded afresh."""
+    path = Path(path)
+    name = f"harness_driver_{path.stem}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def drive(config: dict, mix: dict, seed: int, seconds: float, *,
           trace_dir: str | None, devices, t_process0: float,
-          log=print) -> dict:
+          log=print, drivers: Path = DRIVERS) -> dict:
     """Run the cell once; returns everything the result line is made of."""
     import jax
 
-    from repro.core.geometry import SE3
-    from repro.events.simulator import EventStream, Trajectory
-
     compiles = CompileCounter()
+    drv = driver(config, drivers)
     setup = ref.Setup.from_config(config)
-    e = setup.events_per_frame
     warm, window = float(mix["warmup_s"]), float(seconds)
     t_close = warm + window
     cameras = make_cameras(config, mix, seed)
-    the_plan = plan(cameras, mix, setup, t_close + 2.0)
-    caps = {capacity(b - a) for segs, due in zip(the_plan.segments,
-                                                 the_plan.last_due)
-            for (a, b), d in zip(segs, due) if d < t_close + 1.0}
-    cam, dsi_cfg, opts, engine = build_program(config)
-    sessions, trajs = [], []
-    for i, c in enumerate(cameras):
-        times, rot, pos = c.pose_table(mix, t_close + 5.0)
-        traj = Trajectory(jax.numpy.asarray(times),
-                          SE3(jax.numpy.asarray(rot), jax.numpy.asarray(pos)))
-        trajs.append(traj)
-        sessions.append(engine.add_session(f"cam{i}", traj=traj))
-    warm_programs(cam, dsi_cfg, opts, config["stream"]["segment_buckets"],
-                  caps, e, int(trajs[0].times.shape[0]),
-                  frames_per_push(cameras, mix, e, t_close))
+    the_plan = drv.plan(cameras, mix, setup, t_close + 2.0)
+    served = drv.serve(config, mix, cameras, the_plan, t_close, log)
     log(f"set-up: {len(cameras)} cameras, lap {cameras[0].period:.3f} s of "
-        f"{cameras[0].lap_events} events each; capacities {sorted(caps)}; "
+        f"{cameras[0].lap_events} events each; "
         f"{compiles.between(0, math.inf)} programs compiled or loaded")
 
     spans = Spans(annotate=trace_dir is not None)
@@ -245,18 +153,13 @@ def drive(config: dict, mix: dict, seed: int, seconds: float, *,
     nxt = [p.next() for p in packetizers]
     stats_open = None
 
-    def take(results_by_cam):
+    def take(ready):
         with spans.span("bench.fetch"):
-            for i, results in results_by_cam:
-                for res in results:
-                    depth, mask = jax.device_get((res.depth_map.depth,
-                                                  res.depth_map.mask))
-                    t_emit = time.perf_counter() - wall0
-                    a, b = res.frame_range
-                    due = cameras[i].start + float(
-                        cameras[i].times(b * e - 1, b * e)[0])
-                    emitted.append(Emitted(i, (a, b), t_emit, t_emit - due,
-                                           depth, mask, res))
+            for stream, res in ready:
+                frames, events, due, depth, mask = served.fetch(stream, res)
+                t_emit = time.perf_counter() - wall0
+                emitted.append(Emitted(stream, frames, events, t_emit,
+                                       t_emit - due, depth, mask, res))
 
     wall0 = time.perf_counter()
     window_open = window_close = win_ctx = None
@@ -272,7 +175,7 @@ def drive(config: dict, mix: dict, seed: int, seconds: float, *,
                 win_ctx = jax.profiler.TraceAnnotation("bench.window")
                 win_ctx.__enter__()
             window_open = time.perf_counter()
-            stats_open = _dispatcher_stats(engine)
+            stats_open = served.stats()
         if now >= t_close:
             window_close = time.perf_counter()
             break
@@ -282,9 +185,7 @@ def drive(config: dict, mix: dict, seed: int, seconds: float, *,
             xy, t, pol, valid = cameras[i].events(pkt.g0, pkt.g1)
             with spans.span("bench.push"):
                 try:
-                    out = sessions[i].push(EventStream(xy=xy, t=t,
-                                                       polarity=pol,
-                                                       valid=valid))
+                    out = served.push(i, xy, t, pol, valid)
                 except Exception as exc:  # the engine refused the packet
                     failed += 1
                     log(f"push refused: {type(exc).__name__}: {exc}")
@@ -292,14 +193,13 @@ def drive(config: dict, mix: dict, seed: int, seconds: float, *,
             lags.append((pkt.due, now - pkt.due))
             nxt[i] = packetizers[i].next()
             if out:
-                take([(i, out)])
+                take(out)
             if now - last_poll < POLL_BUSY_S:
                 continue
         if now - last_poll >= POLL_IDLE_S or pkt.due <= now:
             with spans.span("bench.poll"):
-                polled = engine.poll()
+                ready = served.poll()
             last_poll = now
-            ready = [(int(sid[3:]), r) for sid, r in polled.items() if r]
             if ready:
                 take(ready)
         wait = min(nxt[i].due, t_close if window_open else warm) - (
@@ -309,12 +209,13 @@ def drive(config: dict, mix: dict, seed: int, seconds: float, *,
                 time.sleep(min(wait, SLEEP_S))
     if win_ctx is not None:
         win_ctx.__exit__(None, None, None)
-    stats_close = _dispatcher_stats(engine)
+    stats_close = served.stats()
     memory = _memory_peak(devices)
     if trace_dir is not None:
         jax.profiler.stop_trace()
     return {
-        "cameras": cameras, "plan": the_plan, "setup": setup, "engine": engine,
+        "driver": drv, "cameras": cameras, "plan": the_plan, "setup": setup,
+        "served": served,
         "emitted": emitted, "lags": lags, "failed": failed, "spans": spans.rows,
         "window": (warm, t_close),
         "setup_s": window_open - t_process0,
@@ -323,15 +224,6 @@ def drive(config: dict, mix: dict, seed: int, seconds: float, *,
         "compiles_in_window": compiles.between(window_open, window_close),
         "compiles_total": len(compiles.times),
     }
-
-
-def _dispatcher_stats(engine) -> dict:
-    d = engine.dispatcher.stats
-    return {"segments": d["segments"], "dispatches": d["dispatches"],
-            "padded_segments": d["padded_segments"],
-            "pending_segments": d["pending_segments"],
-            "queue_wait_count": d["queue_wait_s"]["count"],
-            "queue_wait_total_s": d["queue_wait_s"]["total_s"]}
 
 
 def _memory_peak(devices) -> int | None:

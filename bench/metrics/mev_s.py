@@ -1,9 +1,9 @@
-"""Millions of events per second completed: the events of every segment
-whose depth map was emitted in the window, over the window's length."""
+"""Millions of events per second completed: the events of every map
+emitted in the window, as its driver counts them, over the window's
+length."""
 
 
 def read(run):
     if not run.emitted:
         return None
-    frames = sum(b - a for a, b in (m.frames for m in run.emitted))
-    return frames * run.events_per_frame / run.window_s / 1e6
+    return sum(m.events for m in run.emitted) / run.window_s / 1e6

@@ -6,8 +6,9 @@
 
 The cell (BENCHMARK.json `workloads`) names a configuration
 (`bench/configs/<config>.json`) and a traffic mix
-(`bench/traffic/<traffic>.json`); each metric is read by
-`bench/metrics/<name>.py`. Without a TPU, or with fewer chips than the
+(`bench/traffic/<traffic>.json`); the configuration's driver
+(`bench/harness/drivers/<driver>.py`, `sessions` where it names none)
+serves it; each metric is read by `bench/metrics/<name>.py`. Without a TPU, or with fewer chips than the
 cell asks for, the run exits non-zero and prints no result. The last
 line of stdout is the result as one JSON object; the numbers compared
 for `correct` are also the last lines of stderr.

@@ -7,6 +7,8 @@ from conftest import load
 from harness import reference, serve
 from harness.traffic import Packetizer, make_cameras
 
+sessions = serve.driver({})
+
 CELLS = [("davis240", "fleet8.overload"),
          ("vga640", "stereo.overload")]
 
@@ -36,8 +38,8 @@ def test_every_seed_has_the_same_sizes(config_name, mix_name):
     for seed in (1, 2, 3 + 2**32):
         cams = make_cameras(config, mix, seed)
         laps.add(tuple(c.lap_events for c in cams))
-        p = serve.plan(cams, mix, setup, 12.0)
-        caps |= {serve.capacity(b - a) for segs in p.segments for a, b in segs}
+        p = sessions.plan(cams, mix, setup, 12.0)
+        caps |= {sessions.capacity(b - a) for segs in p.segments for a, b in segs}
     assert len(laps) == 1
     assert len(caps) == 1, caps
     for n in laps.pop():
@@ -50,7 +52,7 @@ def test_segment_count_follows_the_path():
     config, mix = load("configs", "davis240"), small("fleet8.overload")
     setup = reference.Setup.from_config(config)
     cams = make_cameras(config, mix, 7)
-    p = serve.plan(cams, mix, setup, 20.0)
+    p = sessions.plan(cams, mix, setup, 20.0)
     t_seg = 2 * np.arcsin(0.4125 / (2 * mix["radius_m"])) * mix["radius_m"] / mix["speed_m_s"]
     for segs in p.segments:
         assert len(segs) == pytest.approx(20.0 / t_seg, abs=1.5)
@@ -84,7 +86,7 @@ def test_packets_are_sliced_by_time_or_count(packet_s, packet_events):
             assert t[-1] - t[0] <= packet_s
         g, due = p.g1, p.due
     if packet_s > 1.0:  # count-sliced: every push completes the same frames
-        fpp = serve.frames_per_push(cams, mix, 1024, 20.0)
+        fpp = sessions.frames_per_push(cams, mix, 1024, 20.0)
         assert fpp == {packet_events // 1024}
     # replayed laps keep time moving forward
     t = cam.times(0, 3 * cam.lap_events)
