@@ -132,6 +132,11 @@ class SegmentBatch(NamedTuple):
 
     Padded frame slots repeat the segment's last real frame so their
     geometry stays finite; `frame_valid` zeroes their vote weight.
+
+    A rig batch (`pad_rig_rows`) carries K cameras per map: the frame
+    fields gain a camera axis after S (xy (S, K, C, E, 2), ...), while
+    `ref_R` / `ref_t` stay one reference view per map (the first
+    camera's key frame). The sweep tells the two apart by `xy.ndim`.
     """
 
     xy: Array  # (S, C, E, 2) rectified event coords
@@ -276,10 +281,16 @@ def dispatch_group_head(segs: Sequence[tuple[int, int]], max_group: int,
     return len(indices), cap, sealed
 
 
+def segment_shape(tag: Any, seg: tuple[int, int]) -> tuple[int, int]:
+    """What a camera's segment sweeps, `(rows, frames)`: one row of its
+    `end - start` frames."""
+    return 1, seg[1] - seg[0]
+
+
 def dispatch_group_head_tagged(queue: Sequence[tuple[Any, tuple[int, int]]],
                                max_group: int,
                                minimum: int = SEGMENT_BUCKET_MIN, *,
-                               anchor: int = 0
+                               anchor: int = 0, shape=segment_shape
                                ) -> tuple[list[int], int, bool]:
     """Head group of a TAGGED coalescing queue: `(indices, capacity, sealed)`.
 
@@ -300,6 +311,11 @@ def dispatch_group_head_tagged(queue: Sequence[tuple[Any, tuple[int, int]]],
     the group can never grow (it is full, or some queued segment was left
     behind). With one tag and `anchor=0` this reduces exactly to the
     untagged head group.
+
+    `shape(tag, seg) -> (rows, frames)` says what one item sweeps: its
+    rows per map (1 for a camera, 2 for a rig) and the frames its
+    capacity must hold; members of a group share rows and
+    `bucket_capacity(frames)`.
     """
     if not queue:
         raise ValueError("dispatch_group_head needs a non-empty queue")
@@ -308,7 +324,12 @@ def dispatch_group_head_tagged(queue: Sequence[tuple[Any, tuple[int, int]]],
     if not 0 <= anchor < len(queue):
         raise ValueError(
             f"anchor {anchor} outside queue of {len(queue)} item(s)")
-    tag0, (s0, e0) = queue[anchor]
+
+    def key(tag, seg) -> tuple[int, int]:
+        rows, frames = shape(tag, seg)
+        return rows, bucket_capacity(frames, minimum)
+
+    tag0, seg0 = queue[anchor]
     blocked = set()
     for j in range(anchor):
         tag, _ = queue[j]
@@ -318,18 +339,18 @@ def dispatch_group_head_tagged(queue: Sequence[tuple[Any, tuple[int, int]]],
                 f"at index {anchor} would overtake an earlier segment of "
                 "the same stream (per-stream FIFO)")
         blocked.add(tag)
-    cap = bucket_capacity(e0 - s0, minimum)
+    key0 = key(tag0, seg0)
     indices = [anchor]
     for i in range(anchor + 1, len(queue)):
         if len(indices) == max_group:
             break
-        tag, (s, e) = queue[i]
-        if tag in blocked or bucket_capacity(e - s, minimum) != cap:
+        tag, seg = queue[i]
+        if tag in blocked or key(tag, seg) != key0:
             blocked.add(tag)
             continue
         indices.append(i)
     sealed = len(indices) == max_group or len(indices) < len(queue)
-    return indices, cap, sealed
+    return indices, key0[1], sealed
 
 
 class DispatchPlanner:
@@ -365,7 +386,7 @@ class DispatchPlanner:
 
     def __init__(self, s_buckets: Sequence[int],
                  minimum: int = SEGMENT_BUCKET_MIN, *,
-                 cost_model=None, variant_of=None):
+                 cost_model=None, variant_of=None, shape=segment_shape):
         s_buckets = tuple(s_buckets)
         if not s_buckets:
             raise ValueError("s_buckets must be non-empty")
@@ -378,6 +399,7 @@ class DispatchPlanner:
         self.minimum = minimum
         self.cost_model = cost_model
         self.variant_of = variant_of
+        self.shape = shape  # see dispatch_group_head_tagged
 
     # --- partitioning (the PR 5/6 rules, verbatim) ------------------------
 
@@ -387,7 +409,8 @@ class DispatchPlanner:
     def head_tagged(self, queue: Sequence[tuple[Any, tuple[int, int]]], *,
                     anchor: int = 0) -> tuple[list[int], int, bool]:
         return dispatch_group_head_tagged(queue, self.max_group,
-                                          self.minimum, anchor=anchor)
+                                          self.minimum, anchor=anchor,
+                                          shape=self.shape)
 
     def plan(self, segs: Sequence[tuple[int, int]]
              ) -> list[tuple[list[tuple[int, int]], int]]:
@@ -593,37 +616,52 @@ def pad_segment_rows(rows: Sequence[tuple[EventFrames, tuple[int, int]]],
             "pad_segment_rows needs at least one segment row: an empty "
             "group has no reference pose and nothing to sweep (callers "
             "must skip dispatch for empty buckets)")
-    xy_rows, valid_rows, fv_rows = [], [], []
-    pr_rows, pt_rows, ref_r, ref_t = [], [], [], []
-    for frames, (start, end) in rows:
-        n = end - start
-        xy = np.asarray(frames.xy)
-        if not 0 < n <= capacity:
-            raise ValueError(
-                f"segment {(start, end)} does not fit capacity {capacity}")
-        if not 0 <= start < end <= xy.shape[0]:
-            raise ValueError(f"segment {(start, end)} outside its window of "
-                             f"{xy.shape[0]} frame(s)")
-        idx = np.minimum(np.arange(start, start + capacity), end - 1)
-        valid = np.asarray(frames.valid)
-        poses_R = np.asarray(frames.poses.R)
-        poses_t = np.asarray(frames.poses.t)
-        xy_rows.append(xy[idx])
-        valid_rows.append(valid[idx].astype(np.float32))
-        fv_rows.append((np.arange(capacity) < n).astype(np.float32))
-        pr_rows.append(poses_R[idx])
-        pt_rows.append(poses_t[idx])
-        ref_r.append(poses_R[start])
-        ref_t.append(poses_t[start])
-    return SegmentBatch(
-        xy=jnp.asarray(np.stack(xy_rows)),
-        valid=jnp.asarray(np.stack(valid_rows)),
-        frame_valid=jnp.asarray(np.stack(fv_rows)),
-        poses_R=jnp.asarray(np.stack(pr_rows)),
-        poses_t=jnp.asarray(np.stack(pt_rows)),
-        ref_R=jnp.asarray(np.stack(ref_r)),
-        ref_t=jnp.asarray(np.stack(ref_t)),
-    )
+    gathered = [_gather_row(frames, seg, capacity) for frames, seg in rows]
+    return SegmentBatch(*(jnp.asarray(np.stack(col))
+                          for col in zip(*gathered)))
+
+
+def pad_rig_rows(maps: Sequence[Sequence[tuple[EventFrames, tuple[int, int]]]],
+                 capacity: int) -> SegmentBatch:
+    """A rig batch: `maps[k]` holds one `(frames, (start, end))` row per
+    camera, gathered as `pad_segment_rows` gathers a row, stacked on a
+    camera axis after S. Every camera of map k votes into one reference
+    view, the first camera's pose at its row's `start` (the key frame)."""
+    if not maps:
+        raise ValueError("pad_rig_rows needs at least one map")
+    gathered = [[_gather_row(frames, seg, capacity) for frames, seg in m]
+                for m in maps]
+
+    def field(i: int) -> np.ndarray:  # (S, K, ...)
+        return np.stack([np.stack([row[i] for row in rows])
+                         for rows in gathered])
+
+    return SegmentBatch(*(jnp.asarray(field(i)) for i in range(5)),
+                        ref_R=jnp.asarray(field(5)[:, 0]),
+                        ref_t=jnp.asarray(field(6)[:, 0]))
+
+
+def _gather_row(frames: EventFrames, seg: tuple[int, int], capacity: int
+                ) -> tuple[np.ndarray, ...]:
+    """One padded row, in `SegmentBatch` field order: frames
+    [start, end) clamped at the end to `capacity`, and the pose at
+    `start` as the row's reference view."""
+    start, end = seg
+    n = end - start
+    xy = np.asarray(frames.xy)
+    if not 0 < n <= capacity:
+        raise ValueError(
+            f"segment {(start, end)} does not fit capacity {capacity}")
+    if not 0 <= start < end <= xy.shape[0]:
+        raise ValueError(f"segment {(start, end)} outside its window of "
+                         f"{xy.shape[0]} frame(s)")
+    idx = np.minimum(np.arange(start, start + capacity), end - 1)
+    valid = np.asarray(frames.valid)
+    poses_R = np.asarray(frames.poses.R)
+    poses_t = np.asarray(frames.poses.t)
+    return (xy[idx], valid[idx].astype(np.float32),
+            (np.arange(capacity) < n).astype(np.float32), poses_R[idx],
+            poses_t[idx], poses_R[start], poses_t[start])
 
 
 # ---------------------------------------------------------------------------
@@ -724,17 +762,76 @@ def sweep_segment_batch(
     axis — every segment is independent (the DSI resets per key frame),
     so both wrappers run the exact same per-segment program and their
     outputs agree bitwise on the integer/nearest datapaths.
+
+    A rig batch (`xy` of rank 5, see `SegmentBatch`) is swept map by
+    map: each camera's row is voted into the map's reference view, the
+    two stored DSIs are fused by `dsi.fuse_harmonic_mean`, and detection
+    runs once, on the float32 fused volume, which is what the map
+    returns as its DSI. A one-camera batch compiles to the same program
+    as before rigs existed.
     """
     planes = dsi_cfg.planes()
     z0 = planes[dsi_cfg.num_planes // 2]
 
-    def one_segment(seg: SegmentBatch) -> tuple[Array, DepthMap]:
-        T_w_ref = SE3(seg.ref_R, seg.ref_t)
+    def vote(seg: SegmentBatch, T_w_ref: SE3) -> Array:
+        """One row's frames voted into a fresh DSI at `T_w_ref`, stored
+        (XLA formulations)."""
         geoms = precompute_batch_geometry(cam, seg.poses_R, seg.poses_t,
                                           T_w_ref, planes, z0)
+        dsi0 = jnp.zeros(dsi_cfg.shape, dtype=_accum_dtype(opts))
+
+        def body(dsi, frame):
+            xy, valid, fv, H, alpha, beta_x, beta_y = frame
+            geom = FrameGeometry(H, PlaneSweepCoeffs(alpha, beta_x, beta_y))
+            x_i, y_i = project_frame(cam, xy, geom, opts)
+            return vote_frame(dsi, x_i, y_i, valid * fv, cam, opts), None
+
+        dsi, _ = jax.lax.scan(
+            body,
+            dsi0,
+            (seg.xy, seg.valid, seg.frame_valid, geoms.H,
+             geoms.phi.alpha, geoms.phi.beta_x, geoms.phi.beta_y),
+        )
+
+        if opts.quantized:
+            dsi = dsi_lib.storage_roundtrip(dsi)  # int16 store semantics
+        return dsi
+
+    def detect(dsi: Array) -> DepthMap:
+        return detect_and_filter(
+            dsi, planes,
+            threshold_c=opts.detection_threshold_c,
+            min_votes=opts.detection_min_votes,
+            median_filter=opts.median_filter,
+        )
+
+    if batch.xy.ndim == 5:  # a rig: (S, K, C, E, 2)
+        if opts.formulation == "kernel":
+            raise ValueError(
+                "formulation='kernel' votes and reduces one camera per "
+                "segment in-kernel; a rig's fused sweep runs 'matmul' or "
+                "'scatter'")
+        if batch.xy.shape[1] != 2:
+            raise ValueError(f"a rig fuses 2 cameras by the harmonic mean, "
+                             f"got {batch.xy.shape[1]}")
+
+        def one_map(m: SegmentBatch) -> tuple[Array, DepthMap]:
+            T_w_ref = SE3(m.ref_R, m.ref_t)
+            left, right = (vote(jax.tree.map(lambda a: a[k], m), T_w_ref)
+                           for k in range(2))
+            fused = dsi_lib.fuse_harmonic_mean(left, right)
+            return fused, detect(fused)
+
+        return jax.lax.map(one_map, batch)
+
+    def one_segment(seg: SegmentBatch) -> tuple[Array, DepthMap]:
+        T_w_ref = SE3(seg.ref_R, seg.ref_t)
 
         if opts.formulation == "kernel":
             from repro.kernels.backproject_vote import ops as bpv_ops
+
+            geoms = precompute_batch_geometry(cam, seg.poses_R, seg.poses_t,
+                                              T_w_ref, planes, z0)
 
             # Fused datapath: vote, int16 saturating store (when
             # quantized) and the depth max/argmax reduction all run
@@ -762,31 +859,8 @@ def sweep_segment_batch(
             )
             return dsi, dm
 
-        dsi0 = jnp.zeros(dsi_cfg.shape, dtype=_accum_dtype(opts))
-
-        def body(dsi, frame):
-            xy, valid, fv, H, alpha, beta_x, beta_y = frame
-            geom = FrameGeometry(H, PlaneSweepCoeffs(alpha, beta_x, beta_y))
-            x_i, y_i = project_frame(cam, xy, geom, opts)
-            return vote_frame(dsi, x_i, y_i, valid * fv, cam, opts), None
-
-        dsi, _ = jax.lax.scan(
-            body,
-            dsi0,
-            (seg.xy, seg.valid, seg.frame_valid, geoms.H,
-             geoms.phi.alpha, geoms.phi.beta_x, geoms.phi.beta_y),
-        )
-
-        if opts.quantized:
-            dsi = dsi_lib.storage_roundtrip(dsi)  # int16 store semantics
-
-        dm = detect_and_filter(
-            dsi, planes,
-            threshold_c=opts.detection_threshold_c,
-            min_votes=opts.detection_min_votes,
-            median_filter=opts.median_filter,
-        )
-        return dsi, dm
+        dsi = vote(seg, T_w_ref)
+        return dsi, detect(dsi)
 
     return jax.lax.map(one_segment, batch)
 
@@ -806,7 +880,8 @@ def process_segments_batched(
     keyed on the full batch shape — segment count S, capacity C, events E
     — so distinct sequences can still retrace; a streaming caller should
     pad S to stable sizes.) Returns stacked per-segment DSIs
-    (S, Nz, h, w) and a DepthMap with (S, h, w) fields.
+    (S, Nz, h, w) and a DepthMap with (S, h, w) fields; for a rig batch,
+    one fused float32 DSI and one depth map per map.
 
     This is the `sweep="batched"` backend of `run_emvs`; the
     `sweep="sharded"` backend (`process_segments_sharded`) runs the same
@@ -1028,5 +1103,74 @@ def run_emvs_looped(
         T_w_ref = SE3(frames.poses.R[start], frames.poses.t[start])
         dsi, dm = process_segment(cam, dsi_cfg, sl, T_w_ref, opts)
         results.append(SegmentResult(dm, dsi, T_w_ref, (start, end)))
+        clouds.append(depth_map_to_points(cam, dm, T_w_ref))
+    return EMVSResult(segments=results, clouds=clouds)
+
+
+def rig_right_ranges(t_left, t_right,
+                     segs: Sequence[tuple[int, int]]) -> list[tuple[int, int]]:
+    """The right camera's frames of each of the left camera's key-frame
+    segments [a, b): the frames j with t_L[a] <= t_R[j] < t_L[b], where t
+    is a frame's median timestamp (`EventFrames.t_mid`, in time order). A
+    segment that ends the left stream (b == len(t_left)) has no upper
+    bound. Returns [ra, rb) per segment."""
+    t_left = np.asarray(t_left)
+    t_right = np.asarray(t_right)
+    out = []
+    for a, b in segs:
+        lo = int(np.searchsorted(t_right, t_left[a], side="left"))
+        hi = (t_right.shape[0] if b >= t_left.shape[0] else
+              int(np.searchsorted(t_right, t_left[b], side="left")))
+        out.append((lo, hi))
+    return out
+
+
+def run_emvs_rig_looped(
+    cam: CameraModel,
+    dsi_cfg: DSIConfig,
+    left: EventFrames,
+    right: EventFrames,
+    opts: EMVSOptions = EMVSOptions(),
+) -> EMVSResult:
+    """Reference of a two-camera rig fused at the left camera's key
+    frames (Ghosh & Gallego 2022, arXiv 2207.10494), a host loop in the
+    style of `run_emvs_looped` on the scatter formulation.
+
+    The left (reference) camera's frames are cut into key-frame segments
+    as `plan_segments` cuts them; each segment takes the right frames
+    `rig_right_ranges` assigns it. Both cameras' frames are voted into
+    the left key frame's view, the two DSIs are fused by the harmonic
+    mean in NumPy, divided in float64 and rounded once to float32 (the
+    value `dsi.fuse_harmonic_mean` defines), and detection runs on the
+    fused volume. Both cameras share the intrinsics `cam` (a rectified
+    rig).
+    """
+    opts = dataclasses.replace(opts, formulation="scatter")
+    # one compiled program that computes its planes, as the sweep's
+    # does: op by op, or with the planes as an input, the depth
+    # interpolation rounds differently in the last place
+    detect = jax.jit(lambda dsi: detect_and_filter(
+        dsi, dsi_cfg.planes(), threshold_c=opts.detection_threshold_c,
+        min_votes=opts.detection_min_votes, median_filter=opts.median_filter))
+    segs = plan_segments(left, dsi_cfg, opts)
+    results: list[SegmentResult] = []
+    clouds: list[PointCloud] = []
+    for (a, b), (ra, rb) in zip(segs, rig_right_ranges(left.t_mid,
+                                                       right.t_mid, segs)):
+        T_w_ref = SE3(left.poses.R[a], left.poses.t[a])
+        votes = []
+        for frames, lo, hi in ((left, a, b), (right, ra, rb)):
+            dsi = np.zeros(dsi_cfg.shape, np.float64)
+            if hi > lo:
+                sl = jax.tree.map(lambda x: x[lo:hi], frames)
+                dsi = np.asarray(process_segment(cam, dsi_cfg, sl, T_w_ref,
+                                                 opts)[0], np.float64)
+            votes.append(dsi)
+        va, vb = votes
+        with np.errstate(divide="ignore", invalid="ignore"):
+            fused = np.where((va > 0) & (vb > 0), 2 * va * vb / (va + vb),
+                             0).astype(np.float32)
+        dm = detect(jnp.asarray(fused))
+        results.append(SegmentResult(dm, jnp.asarray(fused), T_w_ref, (a, b)))
         clouds.append(depth_map_to_points(cam, dm, T_w_ref))
     return EMVSResult(segments=results, clouds=clouds)

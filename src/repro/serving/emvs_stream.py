@@ -119,6 +119,7 @@ from repro.events.trajectory_stream import (
 from repro.serving.stream_session import (
     BUDGET_POLICIES,
     MemoryBudgetError,
+    RigSession,
     StreamSession,
     _FrameStore,
 )
@@ -132,6 +133,7 @@ __all__ = [
     "HygieneConfig",
     "MemoryBudgetError",
     "MultiStreamEngine",
+    "RigSession",
     "StreamConfig",
     "StreamHygieneError",
     "StreamSession",
@@ -580,6 +582,14 @@ class MultiStreamEngine:
     the dispatcher's fairness rotation, mirroring `serving/engine.py`'s
     fixed-slot admission. One session's `flush` drains only its own
     work — the rig keeps streaming.
+
+    A stereo rig whose two cameras' DSIs are fused into one map per key
+    frame of the left camera is one session of its own
+    (`add_rig`, `serving.stream_session.RigSession`): pushes name the
+    camera, results come back under the rig's id.
+        rig = engine.add_rig("rig", trajs=(traj_l, traj_r))
+        rig.push(chunk_l, camera=0)    # or engine.push("rig", chunk_l, 0)
+        rig.push(chunk_r, camera=1)
     """
 
     def __init__(self, cam: CameraModel, dsi_cfg: DSIConfig,
@@ -622,7 +632,22 @@ class MultiStreamEngine:
         self._sessions[session_id] = session
         return session
 
-    def session(self, session_id: str) -> StreamSession:
+    def add_rig(self, rig_id: str | None = None,
+                trajs=(None, None)) -> RigSession:
+        """Admit a two-camera rig fused at its left camera's key frames;
+        `trajs` are the left's and the right's known `Trajectory`.
+        Returns its `RigSession` handle."""
+        if rig_id is None:
+            rig_id = f"cam{len(self._sessions)}"
+        if rig_id in self._sessions:
+            raise ValueError(
+                f"duplicate session id {rig_id!r}: already admitted "
+                f"(have {sorted(self._sessions)})")
+        rig = RigSession(rig_id, self.dispatcher, tuple(trajs))
+        self._sessions[rig_id] = rig
+        return rig
+
+    def session(self, session_id: str) -> StreamSession | RigSession:
         try:
             return self._sessions[session_id]
         except KeyError:
@@ -632,8 +657,14 @@ class MultiStreamEngine:
 
     # id-addressed conveniences (the session handles carry the same API)
 
-    def push(self, session_id: str, chunk: EventStream) -> list[SegmentResult]:
-        return self.session(session_id).push(chunk)
+    def push(self, session_id: str, chunk: EventStream,
+             camera: int | None = None) -> list[SegmentResult]:
+        """Push to a session, or to camera `camera` of a rig."""
+        sess = self.session(session_id)
+        if isinstance(sess, RigSession) != (camera is not None):
+            raise ValueError(f"{session_id!r}: a rig's push names its "
+                             f"camera, a session's does not")
+        return sess.push(chunk) if camera is None else sess.push(chunk, camera)
 
     def push_poses(self, session_id: str,
                    chunk: Trajectory) -> list[SegmentResult]:

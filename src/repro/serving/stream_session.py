@@ -17,16 +17,29 @@ A session hands closed segments to its dispatcher tagged with itself and
 gets `SegmentResult`s routed back into `_fresh` / `_done` when the
 device finishes; `repro.serving.emvs_stream.EMVSStreamEngine` is the
 N=1 composition of the two layers, `MultiStreamEngine` the N-camera one.
+
+`RigSession` is a stereo rig's counterpart of a session: two cameras'
+ingest (each with its own hygiene guard, aggregator and frame store)
+behind one key-frame planner, queued on the same dispatcher as one map
+per key frame of the left camera, whose two rows the sweep fuses.
 """
 from __future__ import annotations
 
+import math
 from collections import deque
+from time import perf_counter
+from typing import NamedTuple
 
 import jax
 import numpy as np
 
 from repro.core.geometry import SE3
-from repro.core.pipeline import EMVSResult, SegmentPlanner, SegmentResult
+from repro.core.pipeline import (
+    EMVSResult,
+    SegmentPlanner,
+    SegmentResult,
+    segment_shape,
+)
 from repro.core.pointcloud import PointCloud
 from repro.events.aggregation import EventFrames, StreamingAggregator
 from repro.events.simulator import EventStream, Trajectory
@@ -133,7 +146,113 @@ class _FrameStore:
             self.base += 1
 
 
-class StreamSession:
+def _camera_ingest(dispatcher, traj: Trajectory | TrajectoryBuffer
+                   ) -> tuple[StreamingAggregator, StreamHygiene]:
+    """One camera's ingest per the dispatcher's `StreamConfig`: its
+    aggregator over `traj`, and the hygiene guard every event chunk is
+    scrubbed by before it reaches the aggregator (the camera model
+    supplies the sensor bounds for the out-of-bounds check)."""
+    cfg = dispatcher.stream_cfg
+    aggregator = StreamingAggregator(
+        dispatcher.cam, traj, cfg.events_per_frame,
+        pose_extrapolation=cfg.pose_extrapolation,
+        max_stalled=cfg.max_stalled_frames)
+    hyg = cfg.hygiene
+    if not isinstance(hyg, HygieneConfig):
+        hyg = HygieneConfig(policy=hyg)
+    return aggregator, StreamHygiene(hyg, width=dispatcher.cam.width,
+                                     height=dispatcher.cam.height)
+
+
+class _Session:
+    """What a camera's session and a rig share: registration on the
+    dispatcher, the key-frame planner, the harvested-result stores and
+    the poll / flush / result end of the session contract. A subclass
+    sets `stats` and registers itself."""
+
+    def __init__(self, session_id: str, dispatcher):
+        self.session_id = session_id
+        self.dispatcher = dispatcher
+        mean_depth = 0.5 * (dispatcher.dsi_cfg.z_min + dispatcher.dsi_cfg.z_max)
+        # min_frames=2 is plan_segments' parallax filter, applied online.
+        self.planner = SegmentPlanner(
+            mean_depth * dispatcher.opts.keyframe_dist_frac, min_frames=2)
+        self._fresh: list[SegmentResult] = []  # harvested, not yet polled
+        self._done: dict[tuple[int, int], tuple[SegmentResult, PointCloud]] = {}
+        self._flushed = False
+
+    @staticmethod
+    def _validate_chunk(chunk: EventStream) -> int:
+        """Reject inconsistently shaped chunks before they corrupt the
+        aggregator's remainder; returns the event count."""
+        n = int(np.asarray(chunk.t).shape[0])
+        fields = {"xy": np.asarray(chunk.xy).shape[0],
+                  "polarity": np.asarray(chunk.polarity).shape[0],
+                  "valid": np.asarray(chunk.valid).shape[0]}
+        bad = {name: cnt for name, cnt in fields.items() if cnt != n}
+        if bad:
+            raise ValueError(
+                f"inconsistent event chunk: t has {n} event(s) but "
+                + ", ".join(f"{k} has {v}" for k, v in sorted(bad.items())))
+        return n
+
+    def _scrub(self, hygiene: StreamHygiene,
+               chunk: EventStream) -> EventStream:
+        """Validate one chunk and pass it through `hygiene` (the
+        `emvs.hygiene` span), counting it."""
+        with jax.profiler.TraceAnnotation("emvs.hygiene"):
+            n = self._validate_chunk(chunk)
+            chunk = hygiene.scrub(chunk)
+        self.stats["chunks"] += 1
+        if n == 0:
+            # a legal no-op (e.g. a quiet sensor interval), but an easy
+            # symptom of a broken feed — counted so callers can notice
+            self.stats["empty_chunks"] += 1
+        return chunk
+
+    # --- harvest ----------------------------------------------------------
+
+    def _deliver(self, res: SegmentResult, pc: PointCloud) -> None:
+        """Store one harvested result (the dispatcher routes it here)."""
+        self._done[res.frame_range] = (res, pc)
+        self._fresh.append(res)
+
+    def _take_fresh(self) -> list[SegmentResult]:
+        out, self._fresh = self._fresh, []
+        return out
+
+    def _retry_admission(self) -> None:
+        """Frames a push could not admit retry here (`poll`)."""
+
+    def poll(self) -> list[SegmentResult]:
+        """This session's results that became ready since the last poll:
+        back-pressure harvests plus every in-flight sweep the device has
+        finished. Freed in-flight slots let the shared coalescing queue
+        drain, so a poll can also dispatch segments (of any session) the
+        adaptive policy was holding. Under a memory budget, frames a
+        rejected push left in the admission backlog retry admission here
+        (non-blocking, never raising) as completed sweeps free bytes."""
+        with jax.profiler.TraceAnnotation("emvs.poll"):
+            self._retry_admission()
+            self.dispatcher.pump()
+            return self._take_fresh()
+
+    def _drain(self) -> EMVSResult:
+        """The end of `flush`: drain this session's share of the queue
+        (under every policy) and its in-flight sweeps; the result covers
+        everything, so nothing stays fresh."""
+        self.dispatcher.drain_session(self)
+        self._fresh.clear()
+        return self.result()
+
+    def result(self) -> EMVSResult:
+        """Results harvested so far, in frame order (complete after flush)."""
+        keys = sorted(self._done)
+        return EMVSResult(segments=[self._done[k][0] for k in keys],
+                          clouds=[self._done[k][1] for k in keys])
+
+
+class StreamSession(_Session):
     """One camera's streaming state, multiplexed onto a shared dispatcher.
 
     Construct via `MultiStreamEngine.add_session` (or implicitly through
@@ -158,9 +277,8 @@ class StreamSession:
     def __init__(self, session_id: str,
                  dispatcher,
                  traj: Trajectory | TrajectoryBuffer | None = None):
+        super().__init__(session_id, dispatcher)
         cfg = dispatcher.stream_cfg
-        self.session_id = session_id
-        self.dispatcher = dispatcher
         # traj=None: pose-gated mode with a fresh buffer the caller feeds
         # via push_poses; an existing TrajectoryBuffer (possibly pre-filled)
         # is used as-is; a Trajectory is the offline oracle.
@@ -173,24 +291,8 @@ class StreamSession:
                 "(traj=None or a TrajectoryBuffer): a fully-known "
                 "Trajectory oracle never stalls frames, so the bound "
                 "would silently do nothing")
-        self.aggregator = StreamingAggregator(
-            dispatcher.cam, traj, cfg.events_per_frame,
-            pose_extrapolation=cfg.pose_extrapolation,
-            max_stalled=cfg.max_stalled_frames)
-        mean_depth = 0.5 * (dispatcher.dsi_cfg.z_min + dispatcher.dsi_cfg.z_max)
-        # min_frames=2 is plan_segments' parallax filter, applied online.
-        self.planner = SegmentPlanner(
-            mean_depth * dispatcher.opts.keyframe_dist_frac, min_frames=2)
+        self.aggregator, self.hygiene = _camera_ingest(dispatcher, traj)
         self._store = _FrameStore()
-        # Ingest hygiene: every event chunk is scrubbed against the
-        # stream watermark before it reaches the aggregator (policy per
-        # StreamConfig.hygiene; the camera model supplies the sensor
-        # bounds for the out-of-bounds check).
-        hyg = cfg.hygiene
-        if not isinstance(hyg, HygieneConfig):
-            hyg = HygieneConfig(policy=hyg)
-        self.hygiene = StreamHygiene(hyg, width=dispatcher.cam.width,
-                                     height=dispatcher.cam.height)
         # Memory budget: frames emitted by the aggregator pass through an
         # admission backlog before entering the frame store, so
         # live_bytes NEVER exceeds the budget (checked before append,
@@ -198,9 +300,6 @@ class StreamSession:
         self._budget = cfg.frame_store_budget_bytes
         self._budget_policy = cfg.budget_policy
         self._backlog: deque[tuple] = deque()  # per-frame (xy,valid,t_mid,R,t)
-        self._fresh: list[SegmentResult] = []  # harvested, not yet polled
-        self._done: dict[tuple[int, int], tuple[SegmentResult, PointCloud]] = {}
-        self._flushed = False
         self._tail_flushed = False  # aggregator tail emitted (flush began)
         # Ingestion-side counters; the dispatcher owns the shared dispatch
         # counters and attributes "segments" (dispatched, owned by this
@@ -224,21 +323,6 @@ class StreamSession:
 
     # --- ingest -----------------------------------------------------------
 
-    @staticmethod
-    def _validate_chunk(chunk: EventStream) -> int:
-        """Reject inconsistently shaped chunks before they corrupt the
-        aggregator's remainder; returns the event count."""
-        n = int(np.asarray(chunk.t).shape[0])
-        fields = {"xy": np.asarray(chunk.xy).shape[0],
-                  "polarity": np.asarray(chunk.polarity).shape[0],
-                  "valid": np.asarray(chunk.valid).shape[0]}
-        bad = {name: cnt for name, cnt in fields.items() if cnt != n}
-        if bad:
-            raise ValueError(
-                f"inconsistent event chunk: t has {n} event(s) but "
-                + ", ".join(f"{k} has {v}" for k, v in sorted(bad.items())))
-        return n
-
     def push(self, chunk: EventStream) -> list[SegmentResult]:
         """Feed one event chunk; returns this session's segment results
         that became ready (without blocking — completed sweeps only). In
@@ -260,15 +344,7 @@ class StreamSession:
                 "push after flush: the event tail was already emitted "
                 "(only push_poses / finalize_poses / flush may follow)")
         with jax.profiler.TraceAnnotation("emvs.push"):
-            with jax.profiler.TraceAnnotation("emvs.hygiene"):
-                n = self._validate_chunk(chunk)
-                chunk = self.hygiene.scrub(chunk)
-            self.stats["chunks"] += 1
-            if n == 0:
-                # a legal no-op (e.g. a quiet sensor interval), but an
-                # easy symptom of a broken feed — counted so callers can
-                # notice
-                self.stats["empty_chunks"] += 1
+            chunk = self._scrub(self.hygiene, chunk)
             try:
                 self._ingest(self.aggregator.push(chunk))
             finally:
@@ -412,25 +488,30 @@ class StreamSession:
         self.stats["backlog_frames"] = 0
         self.dispatcher.pump()
 
-    # --- harvest ----------------------------------------------------------
+    # --- what the dispatcher asks of a queued segment ----------------------
 
-    def _take_fresh(self) -> list[SegmentResult]:
-        out, self._fresh = self._fresh, []
-        return out
+    def map_shape(self, seg: tuple[int, int]) -> tuple[int, int]:
+        """(rows, frames) the segment sweeps: one camera's row."""
+        return segment_shape(self, seg)
 
-    def poll(self) -> list[SegmentResult]:
-        """This session's results that became ready since the last poll:
-        back-pressure harvests plus every in-flight sweep the device has
-        finished. Freed in-flight slots let the shared coalescing queue
-        drain, so a poll can also dispatch segments (of any session) the
-        adaptive policy was holding. Under a memory budget, frames a
-        rejected push left in the admission backlog retry admission here
-        (non-blocking, never raising) as completed sweeps free bytes."""
-        with jax.profiler.TraceAnnotation("emvs.poll"):
-            if self._backlog:
-                self._drain_backlog(blocking=False, raise_on_full=False)
-            self.dispatcher.pump()
-            return self._take_fresh()
+    def stage(self, seg: tuple[int, int]) -> list:
+        """The segment's row for `pad_segment_rows`."""
+        start, end = seg
+        return [(self._store.window(start, end), (0, end - start))]
+
+    def evict_behind(self, oldest_queued: tuple[int, int] | None) -> None:
+        """Release retained frames below the oldest queued segment, else
+        below the planner's open segment."""
+        floor = (self.planner.open_start if oldest_queued is None
+                 else oldest_queued[0])
+        self._store.evict_before(floor)
+        self._sync_store_stats()
+
+    def _retry_admission(self) -> None:
+        if self._backlog:
+            self._drain_backlog(blocking=False, raise_on_full=False)
+
+    # --- end of stream ----------------------------------------------------
 
     def flush(self) -> EMVSResult:
         """End of this session's stream: flush the partial frame and the
@@ -478,14 +559,250 @@ class StreamSession:
             if tail is not None:
                 self.dispatcher.enqueue(self, [tail])
             self._flushed = True
-        # end of stream for this session: its share of the coalescing
-        # queue drains fully under every policy
-        self.dispatcher.drain_session(self)
-        self._fresh.clear()  # flush reports everything via result()
-        return self.result()
+        return self._drain()
 
-    def result(self) -> EMVSResult:
-        """Results harvested so far, in frame order (complete after flush)."""
-        keys = sorted(self._done)
-        return EMVSResult(segments=[self._done[k][0] for k in keys],
-                          clouds=[self._done[k][1] for k in keys])
+
+class _RigCamera(NamedTuple):
+    """One camera of a rig: its aggregator, hygiene guard and frames."""
+
+    aggregator: StreamingAggregator
+    hygiene: StreamHygiene
+    store: _FrameStore
+
+    @classmethod
+    def build(cls, dispatcher, traj: Trajectory) -> _RigCamera:
+        if not isinstance(traj, Trajectory):
+            raise ValueError(
+                f"a rig camera's poses are a known Trajectory, got "
+                f"{type(traj).__name__}")
+        return cls(*_camera_ingest(dispatcher, traj), _FrameStore())
+
+
+class _Closed(NamedTuple):
+    """A key-frame segment the left camera closed, waiting for the right
+    camera to pass its end."""
+
+    seg: tuple[int, int]  # left frames [a, b)
+    t_lo: float  # median timestamp of left frame a
+    t_hi: float  # ... of left frame b (inf: the stream's last segment)
+    kept: bool  # False: shorter than the planner's min_frames, dropped
+    t_close: float  # host clock at the close
+
+
+class RigSession(_Session):
+    """A rectified two-camera rig fused at the left camera's key frames
+    (Ghosh & Gallego 2022, arXiv 2207.10494).
+
+    Camera 0 (left) is the reference: its frames alone are cut into
+    key-frame segments, by the same planner as a `StreamSession`'s. The
+    right frame j belongs to the left segment [a, b) when
+    t_L[a] <= t_R[j] < t_L[b], t a frame's median timestamp. A closed
+    segment is held until the right camera's newest frame has
+    t_R >= t_L[b], so every right frame of it has arrived; it is then
+    enqueued as one map of two rows. The dispatcher groups a rig's maps
+    of one shape as it groups segments (`map_shape`); in the sweep both
+    rows are voted into the left key frame's view, fused by
+    `dsi.fuse_harmonic_mean` and detected once
+    (`pipeline.sweep_segment_batch`). Right frames before the first
+    segment, or inside a segment the planner drops (`min_frames`), are
+    dropped and counted. Results are one `SegmentResult` per map: the
+    fused float32 DSI, the left key-frame pose and the left frame range,
+    equal to `pipeline.run_emvs_rig_looped` on the same frames.
+
+    Both cameras share the engine's intrinsics (a rectified rig) and
+    replay known trajectories. The batched sweep's matmul and scatter
+    formulations fuse; the kernel formulation, the sharded sweep and a
+    frame-store budget are refused.
+
+    Counters (`stats`, summed over rigs in the dispatcher's): `rig_holds`
+    maps that left the hold for the queue, `rig_hold_total_s` their
+    summed time from the left close to the enqueue, `rig_frames_dropped`
+    right frames that fell in no map.
+    """
+
+    def __init__(self, session_id: str, dispatcher, trajs):
+        cfg = dispatcher.stream_cfg
+        if cfg.sweep != "batched":
+            raise ValueError(f"a rig runs on the batched sweep, not "
+                             f"{cfg.sweep!r}")
+        if dispatcher.opts.formulation == "kernel":
+            raise ValueError(
+                "formulation='kernel' cannot fuse a rig: it votes and "
+                "reduces one camera per segment in-kernel; use 'matmul' "
+                "or 'scatter'")
+        if (cfg.frame_store_budget_bytes is not None
+                or cfg.max_stalled_frames is not None):
+            raise ValueError("a rig has no frame-store budget and no stall "
+                             "bound: set frame_store_budget_bytes and "
+                             "max_stalled_frames to None")
+        if len(trajs) != 2:
+            raise ValueError(f"a rig has 2 cameras, got {len(trajs)} "
+                             f"trajectories")
+        super().__init__(session_id, dispatcher)
+        self._cams = [_RigCamera.build(dispatcher, traj) for traj in trajs]
+        self._open_t = math.nan  # t_mid of the left's open segment's start
+        self._closed: deque[_Closed] = deque()
+        self._right_t: deque[float] = deque()  # t_mid, unassigned right frames
+        self._right_next = 0  # global index of the first of them
+        self._right_newest = -math.inf  # t_mid of the newest right frame
+        self._right_of: dict[tuple[int, int], tuple[int, int]] = {}
+        self.stats = {"chunks": 0, "empty_chunks": 0, "frames": 0,
+                      "segments": 0, "frame_store_bytes": 0,
+                      "frame_store_peak_bytes": 0,
+                      "dsi_saturation_peak": 0.0, "rig_holds": 0,
+                      "rig_hold_total_s": 0.0, "rig_frames_dropped": 0,
+                      "hygiene": [c.hygiene.stats for c in self._cams]}
+        dispatcher.register(self)
+
+    def _camera(self, camera: int) -> _RigCamera:
+        if camera not in (0, 1):
+            raise ValueError(f"a rig's cameras are 0 (left, the reference) "
+                             f"and 1 (right), got {camera!r}")
+        return self._cams[camera]
+
+    # --- ingest -----------------------------------------------------------
+
+    def push(self, chunk: EventStream, camera: int) -> list[SegmentResult]:
+        """Feed one event chunk of `camera`; returns the rig's maps that
+        became ready, as `StreamSession.push` does."""
+        cam = self._camera(camera)
+        if self._flushed:
+            raise RuntimeError(
+                "push after flush: the event tails were already emitted")
+        with jax.profiler.TraceAnnotation("emvs.push"):
+            chunk = self._scrub(cam.hygiene, chunk)
+            self._ingest(camera, cam.aggregator.push(chunk))
+            return self.poll()
+
+    def _sync_store_stats(self) -> None:
+        live = sum(c.store.live_bytes for c in self._cams)
+        self.stats["frame_store_bytes"] = live
+        self.stats["frame_store_peak_bytes"] = max(
+            self.stats["frame_store_peak_bytes"], live)
+
+    def _ingest(self, camera: int, frames: EventFrames) -> None:
+        """Store a camera's new frames; cut the left's into segments;
+        enqueue the segments the right camera has passed."""
+        with jax.profiler.TraceAnnotation("emvs.plan"):
+            n = int(frames.xy.shape[0])
+            if n == 0:
+                return
+            self.stats["frames"] += n
+            self._cams[camera].store.extend(frames)
+            self._sync_store_stats()
+            t_mid = np.asarray(frames.t_mid)
+            if camera == 1:
+                self._right_t.extend(float(t) for t in t_mid)
+                self._right_newest = float(t_mid[-1])
+            else:
+                if self.planner.num_frames == 0:
+                    self._open_t = float(t_mid[0])
+                t_host = np.asarray(frames.poses.t)
+                for k in range(n):
+                    start = self.planner.open_start
+                    seg = self.planner.push(t_host[k])
+                    if self.planner.open_start != start:
+                        self._close((start, self.planner.open_start),
+                                    seg is not None, float(t_mid[k]))
+            self._assign(final=False)
+            self.dispatcher.pump()
+
+    def _close(self, seg: tuple[int, int], kept: bool, t_hi: float) -> None:
+        self._closed.append(_Closed(seg, self._open_t, t_hi, kept,
+                                    perf_counter()))
+        self._open_t = t_hi
+
+    def _assign(self, final: bool) -> None:
+        """Give each closed segment the right frames of its interval once
+        the right camera has passed its end (every one, at `final`), and
+        enqueue the kept ones as maps."""
+        if not self._closed or not (
+                final or self._right_newest >= self._closed[0].t_hi):
+            return
+        with jax.profiler.TraceAnnotation("emvs.rig.assign"):
+            maps, dropped = [], 0
+            while self._closed and (
+                    final or self._right_newest >= self._closed[0].t_hi):
+                c = self._closed.popleft()
+                while self._right_t and self._right_t[0] < c.t_lo:
+                    self._right_t.popleft()  # before the first segment
+                    self._right_next += 1
+                    dropped += 1
+                lo = self._right_next
+                while self._right_t and self._right_t[0] < c.t_hi:
+                    self._right_t.popleft()
+                    self._right_next += 1
+                if not c.kept:
+                    dropped += self._right_next - lo
+                    continue
+                self._right_of[c.seg] = (lo, self._right_next)
+                hold = perf_counter() - c.t_close
+                for stats in (self.stats, self.dispatcher.stats):
+                    stats["rig_holds"] += 1
+                    stats["rig_hold_total_s"] += hold
+                maps.append(c.seg)
+            for stats in (self.stats, self.dispatcher.stats):
+                stats["rig_frames_dropped"] += dropped
+            if maps:
+                self.dispatcher.enqueue(self, maps)
+
+    # --- what the dispatcher asks of a queued map --------------------------
+
+    def map_shape(self, seg: tuple[int, int]) -> tuple[int, int]:
+        """(rows, frames) the map sweeps: two rows, padded to the longer."""
+        lo, hi = self._right_of[seg]
+        return 2, max(seg[1] - seg[0], hi - lo)
+
+    def stage(self, seg: tuple[int, int]) -> list:
+        """The map's left and right rows for `pad_rig_rows`."""
+        a, b = seg
+        lo, hi = self._right_of[seg]
+        left = self._cams[0].store.window(a, b)
+        if hi > lo:
+            right = (self._cams[1].store.window(lo, hi), (0, hi - lo))
+        else:  # no right frame in the interval: a row that votes nothing
+            right = (left._replace(valid=np.zeros_like(left.valid)),
+                     (0, b - a))
+        return [(left, (0, b - a)), right]
+
+    def evict_behind(self, oldest_queued: tuple[int, int] | None) -> None:
+        """Release frames no queued, held or open map needs."""
+        if oldest_queued is not None:
+            left_floor = oldest_queued[0]
+            right_floor = self._right_of[oldest_queued][0]
+        else:
+            left_floor = (self._closed[0].seg[0] if self._closed
+                          else self.planner.open_start)
+            right_floor = self._right_next
+        self._cams[0].store.evict_before(left_floor)
+        self._cams[1].store.evict_before(right_floor)
+        for seg in [s for s in self._right_of if s[0] < left_floor]:
+            del self._right_of[seg]
+        self._sync_store_stats()
+
+    # --- harvest ----------------------------------------------------------
+
+    def _deliver(self, res: SegmentResult, pc: PointCloud) -> None:
+        with jax.profiler.TraceAnnotation("emvs.rig.fuse"):
+            super()._deliver(res, pc)
+
+    # --- end of stream ----------------------------------------------------
+
+    def flush(self) -> EMVSResult:
+        """End of both cameras' streams: emit their tails, close the
+        left's last segment (it takes every right frame from its start
+        on), release every held segment and drain the rig's maps."""
+        if not self._flushed:
+            self._flushed = True
+            for k, cam in enumerate(self._cams):
+                held = cam.hygiene.flush()
+                if held.t.shape[0]:
+                    self._ingest(k, cam.aggregator.push(held))
+                self._ingest(k, cam.aggregator.flush())
+            start = self.planner.open_start
+            tail = self.planner.flush()
+            if self.planner.open_start != start:
+                self._close((start, self.planner.open_start),
+                            tail is not None, math.inf)
+            self._assign(final=True)
+        return self._drain()

@@ -20,6 +20,11 @@ interleaving, policy, and fairness setting. Harvested rows are routed
 back to their owning session's result stores; one session's `flush`
 drains only its share of the queue (same-capacity neighbors may ride
 along — legal for the same independence reason).
+
+A queue item asks its session what it sweeps (`map_shape`, `stage`):
+a camera's segment is one row, a rig's map (`RigSession`) two rows that
+the sweep fuses. A group holds items of one shape only, so rig maps
+group with rig maps under the same policy as segments with segments.
 """
 from __future__ import annotations
 
@@ -38,6 +43,7 @@ from repro.core.pipeline import (
     DispatchPlanner,
     EMVSOptions,
     SegmentResult,
+    pad_rig_rows,
     pad_segment_rows,
     process_segments_batched,
 )
@@ -213,7 +219,8 @@ class SweepDispatcher:
         self.profiler = profiler
         self.planner = DispatchPlanner(
             self._segment_buckets, cost_model=cost_model,
-            variant_of=self._variant_key)
+            variant_of=self._variant_key,
+            shape=lambda sess, seg: sess.map_shape(seg))
         self._sessions: list = []  # registration = round-robin order
         self._rr_cursor = 0
         self.default_owner = None  # harvest target for untagged in-flight
@@ -239,7 +246,8 @@ class SweepDispatcher:
         # time comes from a profiler trace. backpressure_harvests counts
         # the harvests that blocked because every in-flight slot was
         # full (in _dispatch and make_room), i.e. dispatches that stalled
-        # the pushing client.
+        # the pushing client. rig_holds / rig_hold_total_s /
+        # rig_frames_dropped sum the rigs' own counters (RigSession).
         self._queue_wait_hist = _LatencyHist()
         self._sweep_time_hist = _LatencyHist()
         self._session_wait_hists: dict[int, _LatencyHist] = {}
@@ -250,6 +258,8 @@ class SweepDispatcher:
                       "cross_stream_dispatches": 0,
                       "slo_dispatches": 0, "slo_holds": 0,
                       "backpressure_harvests": 0,
+                      "rig_holds": 0, "rig_hold_total_s": 0.0,
+                      "rig_frames_dropped": 0,
                       "queue_wait_s": self._queue_wait_hist.snapshot(),
                       "sweep_time_s": self._sweep_time_hist.snapshot()}
 
@@ -298,12 +308,12 @@ class SweepDispatcher:
         self.stats["pending_segments"] = d
         self.stats["max_pending"] = max(self.stats["max_pending"], d)
 
-    def _oldest_pending_start(self, session) -> int | None:
+    def _oldest_pending(self, session) -> tuple[int, int] | None:
         # per-session FIFO holds in the tagged queue, so a session's first
         # occurrence is its oldest queued segment
-        for sess, (start, _) in self._pending:
+        for sess, seg in self._pending:
             if sess is session:
-                return start
+                return seg
         return None
 
     def _evict_all(self) -> None:
@@ -312,11 +322,7 @@ class SweepDispatcher:
         # segment: a queued group references frames the planner already
         # moved past
         for sess in self._sessions:
-            floor = self._oldest_pending_start(sess)
-            if floor is None:
-                floor = sess.planner.open_start
-            sess._store.evict_before(floor)
-            sess._sync_store_stats()
+            sess.evict_behind(self._oldest_pending(sess))
 
     def make_room(self, session, blocking: bool) -> bool:
         """Free retained frame-store bytes for `session`'s budget admission.
@@ -449,7 +455,7 @@ class SweepDispatcher:
                     and len(self._inflight) >= self.stream_cfg.max_inflight):
                 return None  # device saturated: coalesce until a slot frees
         for sess in self._anchor_candidates(only):
-            if only is not None and self._oldest_pending_start(sess) is None:
+            if only is not None and self._oldest_pending(sess) is None:
                 return None  # the drained session has nothing queued
             anchor = next(i for i, (s, _) in enumerate(self._pending)
                           if s is sess)
@@ -544,9 +550,11 @@ class SweepDispatcher:
             # per-segment independent, so they are pure discarded work
             padded = list(group) + [group[-1]] * (s_pad - len(group))
             with jax.profiler.TraceAnnotation("emvs.stage"):
-                rows = [(sess._store.window(start, end), (0, end - start))
-                        for sess, (start, end) in padded]
-                batch = pad_segment_rows(rows, cap)
+                # one row per camera of each map; a group shares its rows
+                # per map (DispatchPlanner's shape)
+                maps = [sess.stage(seg) for sess, seg in padded]
+                batch = (pad_rig_rows(maps, cap) if len(maps[0]) > 1 else
+                         pad_segment_rows([rows[0] for rows in maps], cap))
             # async dispatch: both calls below return with the sweep
             # enqueued, so the caller stages the next batch while this
             # one votes
@@ -636,5 +644,4 @@ class SweepDispatcher:
                                     (start, end))
                 pc = PointCloud(inf.pcs.points[k], inf.pcs.weights[k],
                                 inf.pcs.valid[k])
-                sess._done[(start, end)] = (res, pc)
-                sess._fresh.append(res)
+                sess._deliver(res, pc)

@@ -13,6 +13,8 @@ driven through it. Pinned here:
   * enters and exits balance when a push raises (hygiene,
     `PoseStallError`);
   * the `backpressure_harvests` counter matches its span;
+  * a rig's `emvs.rig.assign` nests in push and plan, its
+    `emvs.rig.fuse` in harvest, and its hold counters move;
   * results are bitwise-equal to an unrecorded run.
 """
 from __future__ import annotations
@@ -228,3 +230,42 @@ def test_results_are_bitwise_equal_to_an_unrecorded_run(cam, scene,
             for x, y in ((g.dsi, w.dsi), (g.depth_map.depth, w.depth_map.depth),
                          (g.depth_map.mask, w.depth_map.mask)):
                 np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_rig_spans_nest_in_push_and_harvest(cam, scene, recorder):
+    """A rig's push that releases held maps assigns right frames in
+    `emvs.rig.assign` under `emvs.push` / `emvs.plan`, before it
+    dispatches; each harvest of fused maps routes them in
+    `emvs.rig.fuse` under `emvs.harvest`. The hold counters move."""
+    from repro.core.geometry import SE3
+    from repro.events.simulator import Trajectory
+
+    ev, traj, dsi_cfg = scene
+    shift = np.asarray(traj.poses.t) + np.float32([0.1, 0.0, 0.0])
+    right = Trajectory(traj.times, SE3(traj.poses.R, shift.astype(np.float32)))
+    engine = _engine(cam, dsi_cfg, max_inflight=1)
+    rig = engine.add_rig("rig", trajs=(traj, right))
+    half = 40 * EVENTS_PER_FRAME
+    rig.push(_chunk(ev, 0, half), camera=0)
+    left_only = Counter(n for n, _, _ in _walk(recorder.forest()))
+    assert left_only["emvs.rig.assign"] == left_only["emvs.dispatch"] == 0
+    assert rig.stats["rig_holds"] == 0 and len(rig._closed) > 1
+    mark = recorder.mark()
+    rig.push(_chunk(ev, 0, half), camera=1)
+    _drain_by_polling(engine)
+    roots = recorder.forest(mark)
+    assert _names(roots) == ["emvs.push", "emvs.poll"]
+    plan = dict(roots[0][1])["emvs.plan"]
+    assert _names(plan)[0] == "emvs.rig.assign"
+    assert "emvs.dispatch" in _names(plan)
+    for name, kids, path in _walk(roots):
+        if name == "emvs.rig.assign":
+            assert path == ("emvs.push", "emvs.plan") and kids == []
+        elif name == "emvs.rig.fuse":
+            assert path[-1] == "emvs.harvest" and kids == []
+    spans = Counter(n for n, _, _ in _walk(roots))
+    stats, d = rig.stats, engine.dispatcher.stats
+    assert spans["emvs.rig.assign"] == 1
+    assert spans["emvs.rig.fuse"] == spans["emvs.harvest"] == d["dispatches"] > 1
+    assert stats["rig_holds"] == d["rig_holds"] == d["segments"] > 1
+    assert stats["rig_hold_total_s"] == d["rig_hold_total_s"] > 0.0

@@ -2,7 +2,8 @@
 
 Nothing runs: `jax.experimental.topologies` describes a v5e chip that is
 not attached, and each test compiles one program for it at the paper's
-operating point (240x180, 128 planes, 1024-event frames). The TPU
+operating point (240x180, 128 planes, 1024-event frames), the fused
+rig's at VGA. The TPU
 compiler refuses here what it would refuse on the chip — a block layout
 Mosaic cannot tile, an op it cannot lower, a kernel over its VMEM
 budget, a program over the chip's memory.
@@ -138,6 +139,30 @@ def test_batched_sweep_compiles_for_v5e(one_chip, geometry, compiled_pallas,
     assert mem.output_size_in_bytes >= dsi_bytes
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 16e9 / 4
     assert ("tpu_custom_call" in compiled.as_text()) == (formulation == "kernel")
+
+
+def test_fused_rig_sweep_compiles_for_v5e_at_vga(one_chip):
+    """A rig's sweep at `dsec_rig_fused`'s operating point (640x480, 128
+    planes, 888-frame capacity, 4 maps of 2 cameras): one fused float32
+    DSI a map, and the program fits one v5e chip."""
+    cam = CameraModel(width=640, height=480, fx=560.0, fy=560.0, cx=320.0,
+                      cy=240.0)
+    dsi_cfg = DSIConfig.for_camera(cam)
+    s, k, c = 4, 2, 888
+
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    batch = SegmentBatch(xy=sd(s, k, c, E, 2), valid=sd(s, k, c, E),
+                         frame_valid=sd(s, k, c), poses_R=sd(s, k, c, 3, 3),
+                         poses_t=sd(s, k, c, 3), ref_R=sd(s, 3, 3),
+                         ref_t=sd(s, 3))
+    compiled = process_segments_batched.lower(cam, dsi_cfg, batch,
+                                              EMVSOptions()).compile()
+    fused, _ = compiled.out_info
+    assert fused.shape == (s,) + dsi_cfg.shape and fused.dtype == jnp.float32
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 16e9 / 4
 
 
 def test_sharded_sweep_compiles_for_v5e_2x2(topo, geometry):
