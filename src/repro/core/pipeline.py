@@ -73,7 +73,7 @@ from repro.core.detection import DepthMap, detect_and_filter, detect_and_filter_
 from repro.core.dsi import DSIConfig
 from repro.core.geometry import SE3, PlaneSweepCoeffs, apply_homography, propagate_to_planes
 from repro.core.pointcloud import PointCloud, depth_map_to_points, depth_maps_to_points
-from repro.core.voting import vote_onehot_matmul, vote_scatter
+from repro.core.voting import vote_onehot_chunk, vote_onehot_matmul, vote_scatter
 from repro.events.aggregation import EventFrames
 from repro.quant.policies import TABLE1, EMVSQuantPolicy
 
@@ -82,6 +82,11 @@ Array = jax.Array
 # Smallest fixed segment capacity: keeping a floor bounds the number of
 # distinct compiled bucket shapes for trajectories with many tiny segments.
 SEGMENT_BUCKET_MIN = 4
+
+# Frames the nearest matmul sweep votes per DSI update
+# (`voting.vote_onehot_chunk`). Every bucket capacity is a multiple of
+# it, so the served sweeps chunk without padding.
+VOTE_CHUNK_FRAMES = 4
 
 # Fairness policies for the TAGGED coalescing queue (multi-tenant serving):
 #   * "fifo"        — every dispatch group anchors at the global queue head:
@@ -747,6 +752,23 @@ def precompute_segment_geometry(
                                      T_w_ref, planes, z0)
 
 
+def _pad_frames(seg: SegmentBatch, multiple: int) -> SegmentBatch:
+    """One row's frames padded to a multiple of `multiple` by the
+    `SegmentBatch` rule: repeat the last frame, with `frame_valid` 0.
+    Only a capacity no bucket builds (a direct `process_segment` call)
+    needs it."""
+    pad = -seg.xy.shape[0] % multiple
+    if not pad:
+        return seg
+
+    def edge(a: Array) -> Array:
+        return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1), mode="edge")
+
+    return seg._replace(xy=edge(seg.xy), valid=edge(seg.valid),
+                        poses_R=edge(seg.poses_R), poses_t=edge(seg.poses_t),
+                        frame_valid=jnp.pad(seg.frame_valid, (0, pad)))
+
+
 def sweep_segment_batch(
     cam: CameraModel,
     dsi_cfg: DSIConfig,
@@ -776,22 +798,36 @@ def sweep_segment_batch(
     def vote(seg: SegmentBatch, T_w_ref: SE3) -> Array:
         """One row's frames voted into a fresh DSI at `T_w_ref`, stored
         (XLA formulations)."""
+        chunked = opts.formulation == "matmul" and opts.voting == "nearest"
+        if chunked:
+            seg = _pad_frames(seg, VOTE_CHUNK_FRAMES)
         geoms = precompute_batch_geometry(cam, seg.poses_R, seg.poses_t,
                                           T_w_ref, planes, z0)
         dsi0 = jnp.zeros(dsi_cfg.shape, dtype=_accum_dtype(opts))
+        frames = (seg.xy, seg.valid, seg.frame_valid, geoms.H,
+                  geoms.phi.alpha, geoms.phi.beta_x, geoms.phi.beta_y)
 
-        def body(dsi, frame):
-            xy, valid, fv, H, alpha, beta_x, beta_y = frame
+        def project(xy, H, alpha, beta_x, beta_y):
             geom = FrameGeometry(H, PlaneSweepCoeffs(alpha, beta_x, beta_y))
-            x_i, y_i = project_frame(cam, xy, geom, opts)
-            return vote_frame(dsi, x_i, y_i, valid * fv, cam, opts), None
+            return project_frame(cam, xy, geom, opts)
 
-        dsi, _ = jax.lax.scan(
-            body,
-            dsi0,
-            (seg.xy, seg.valid, seg.frame_valid, geoms.H,
-             geoms.phi.alpha, geoms.phi.beta_x, geoms.phi.beta_y),
-        )
+        if chunked:
+            # VOTE_CHUNK_FRAMES frames a step, one int8 contraction each
+            frames = tuple(a.reshape((-1, VOTE_CHUNK_FRAMES) + a.shape[1:])
+                           for a in frames)
+
+            def body(dsi, chunk):
+                xy, valid, fv, *geom = chunk
+                x_i, y_i = jax.vmap(project)(xy, *geom)
+                return vote_onehot_chunk(dsi, x_i, y_i, valid * fv[:, None],
+                                         w=cam.width, h=cam.height), None
+        else:
+            def body(dsi, frame):
+                xy, valid, fv, *geom = frame
+                x_i, y_i = project(xy, *geom)
+                return vote_frame(dsi, x_i, y_i, valid * fv, cam, opts), None
+
+        dsi, _ = jax.lax.scan(body, dsi0, frames)
 
         if opts.quantized:
             dsi = dsi_lib.storage_roundtrip(dsi)  # int16 store semantics

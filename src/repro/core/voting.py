@@ -10,6 +10,19 @@ Three formulations, all numerically reconciled by tests:
                             per depth plane, votes = Ox^T @ Oy with
                             one-hot (nearest) or two-hot (bilinear) event
                             row encodings. Runs on the MXU; no scatter.
+     `vote_onehot_chunk`  — (2) for nearest votes of several frames at
+                            once, as the matmul sweep runs it: int8
+                            one-hot rows of all F·E events of a chunk in
+                            ONE s8 x s8 -> s32 contraction per plane, added
+                            to the int32 DSI once per chunk, so the DSI
+                            makes one HBM round trip per F frames. On the
+                            TPU the row producers and the add fuse into
+                            the contraction; no row reaches HBM. 0/1 rows
+                            and weights make it exact: the same integer
+                            counts as (1) and (2), bit for bit. What is
+                            left is the dense contraction itself,
+                            h·w MACs a plane for each vote, which only a
+                            tile-sparse vote or (3) removes.
   3. kernels/backproject_vote — the Pallas kernel implementing (2) fused
                             with P(Z0->Zi), tiled for VMEM.
 
@@ -163,3 +176,36 @@ def vote_onehot_matmul(
         # disagree with quant/fixed_point at exact half-integer votes
         votes = round_half_away(votes).astype(dsi.dtype)
     return dsi + votes
+
+
+def vote_onehot_chunk(dsi: Array, x_i: Array, y_i: Array, weights: Array, *,
+                      w: int, h: int) -> Array:
+    """Nearest votes of F frames into an integer dsi (Nz, h, w) at once.
+
+    x_i, y_i: (F, Nz, E) projected coords; weights: (F, E) 0/1 vote
+    weights (event validity times frame validity). Per plane, the rows
+    of all F·E events are int8 one-hots and one dot_general contracts
+    them into int32 counts, which are added to the DSI once.
+
+    Exact for 0/1 weights only (a nonzero weight votes 1): the sweep's
+    masks are 0/1 by contract (`SWEEP_INPUT_CONTRACTS`), so this is
+    `vote_onehot_matmul` over the F frames, bit for bit. Callers with
+    fractional weights use `vote_onehot_matmul`.
+    """
+    x_i, y_i = _sanitize(x_i), _sanitize(y_i)
+    nz = x_i.shape[1]
+    keep = _bounds_mask_nearest(x_i, y_i, w, h) & (weights[:, None, :] != 0)
+
+    def rows(coord: Array, size: int, mask: Array | None) -> Array:
+        idx = _round_half_up(coord).astype(jnp.int32)
+        if mask is not None:  # an index off the grid gives an all-zero row
+            idx = jnp.where(mask, idx, -1)
+        idx = jnp.moveaxis(idx, 0, 1).reshape(nz, -1)  # (Nz, F*E)
+        return (idx[..., None] == jnp.arange(size, dtype=jnp.int32)
+                ).astype(jnp.int8)
+
+    # a dropped event needs one all-zero row of its pair, not two
+    oy, ox = rows(y_i, h, keep), rows(x_i, w, None)
+    votes = jax.lax.dot_general(oy, ox, (((1,), (1,)), ((0,), (0,))),
+                                preferred_element_type=jnp.int32)
+    return dsi + votes.astype(dsi.dtype)

@@ -188,6 +188,43 @@ def test_int32_accumulator_headroom_is_proven_not_flagged():
     assert hi < np.iinfo(np.int32).max
 
 
+@pytest.mark.parametrize("quant", ["float", "quant"])
+def test_chunked_vote_is_bounded_at_four_frames_of_events(quant):
+    """The matmul sweep's nearest vote contracts 4 x E int8 one-hot rows
+    a step: the int32 dot is bounded by k = 4 E, and the DSI carry by
+    the proof capacity's votes (plus one step of the closed form's
+    slack), far inside int32."""
+    from repro.analysis.dtype_flow import Rule
+    from repro.core.pipeline import VOTE_CHUNK_FRAMES
+
+    class Record(Rule):
+        rule_id = "record"
+
+        def __init__(self):
+            self.dots, self.carries = [], []
+
+        def on_eqn(self, ctx, eqn, ins, outs):
+            if eqn.primitive.name == "dot_general" and all(
+                    np.dtype(a.dtype) == np.int8 for a in ins):
+                self.dots.append(outs[0])
+            elif eqn.primitive.name == "scan":
+                self.carries += [o for o in outs if np.dtype(o.dtype) == np.int32]
+
+    entry, = [e for e in lint_cli.build_entries("quick")
+              if e["name"] == f"sweep[matmul,batched,nearest,{quant}]"]
+    rec = Record()
+    ctx = analyze_program(entry["fn"], entry["args"], entry["contracts"],
+                          entry=entry["name"], rules=default_rules() + [rec],
+                          sanctioned_clips=entry["policy"].sanctioned_clip_bounds())
+    assert ctx.findings == []
+    k = VOTE_CHUNK_FRAMES * lint_cli.TRACE_EVENTS
+    assert [(np.dtype(d.dtype), d.lo, d.hi, d.known) for d in rec.dots] == [
+        (np.dtype(np.int32), 0, k, True)]
+    votes = lint_cli.PROOF_CAPACITY_FRAMES * lint_cli.TRACE_EVENTS
+    assert rec.carries and all(
+        c.known and votes <= c.hi <= votes + k for c in rec.carries)
+
+
 def test_unknown_ranges_do_not_produce_noise_findings():
     """Unconstrained int inputs carry the dtype-default interval; adding
     two must NOT be reported — overflow findings are proofs only."""

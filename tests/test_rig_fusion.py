@@ -15,6 +15,8 @@ the left camera's x on the simulator's 6-DOF arc, so both see the
   * the fusion is 2ab / (a + b) rounded once to float32, bit for bit,
     and votes too wide for int32 fall back to a float32 divide;
   * one silent camera fuses to zero: no map from a single camera;
+  * the matmul sweep's chunked int8 vote fuses a rig batch bit for bit
+    as the scatter sweep, frame by frame, does;
   * a one-camera session beside a rig keeps its offline result.
 """
 from __future__ import annotations
@@ -28,7 +30,9 @@ from repro.core.dsi import DSIConfig, fuse_harmonic_mean
 from repro.core.geometry import SE3
 from repro.core.pipeline import (
     EMVSOptions,
+    pad_rig_rows,
     plan_segments,
+    process_segments_batched,
     rig_right_ranges,
     run_emvs,
     run_emvs_rig_looped,
@@ -217,6 +221,34 @@ def test_right_frames_outside_kept_segments_are_dropped():
         np.testing.assert_array_equal(np.asarray(g.dsi), np.asarray(w.dsi))
         np.testing.assert_array_equal(np.asarray(g.depth_map.mask),
                                       np.asarray(w.depth_map.mask))
+
+
+@pytest.mark.parametrize("capacity", [4, 8, 12])
+def test_rig_sweep_votes_by_chunks_as_the_per_frame_scatter(rig, capacity):
+    """A rig batch's fused DSI, mask and depth from the matmul sweep,
+    four frames a contraction, are the scatter sweep's bit for bit:
+    invalid events, padding at a row's end and a dropped frame inside a
+    chunk vote nothing in either camera."""
+    left, right = _frames(*rig)
+    low = dict(detection_threshold_c=0.0, detection_min_votes=1.0)
+    opts = _opts(**low)  # a low threshold: most voted pixels detected
+    segs = plan_segments(left, DSI, opts)[:2]
+    maps = [[(frames, (a, min(b, a + capacity - 1)))
+             for frames, (a, b) in ((left, seg), (right, rseg))]
+            for seg, rseg in zip(segs, rig_right_ranges(left.t_mid,
+                                                        right.t_mid, segs))]
+    batch = pad_rig_rows(maps, capacity)
+    rng = np.random.default_rng(capacity)
+    batch = batch._replace(
+        valid=batch.valid * (rng.random(batch.valid.shape) > 0.2),
+        frame_valid=batch.frame_valid.at[:, :, 1].set(0.0))
+    fused, dm = process_segments_batched(CAM, DSI, batch, opts)
+    want, want_dm = process_segments_batched(CAM, DSI, batch,
+                                             _opts("scatter", **low))
+    assert fused.dtype == jnp.float32 and int(jnp.sum(dm.mask)) > 100
+    for a, b in ((fused, want), (dm.mask, want_dm.mask),
+                 (dm.depth, want_dm.depth)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("votes", ["grid", "random", "float", "wide"])

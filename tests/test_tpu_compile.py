@@ -14,6 +14,8 @@ test workers import every test file.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,7 +23,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.camera import CameraModel
 from repro.core.dsi import DSIConfig
-from repro.core.pipeline import EMVSOptions, SegmentBatch, process_segments_batched
+from repro.core.pipeline import (
+    VOTE_CHUNK_FRAMES,
+    EMVSOptions,
+    SegmentBatch,
+    process_segments_batched,
+)
 
 E = 1024  # events per frame
 FRAMES = 8  # frames in one kernel call
@@ -185,3 +192,94 @@ def test_sharded_sweep_compiles_for_v5e_2x2(topo, geometry):
     assert dsi_sharding.spec == P("segments")
     assert compiled.memory_analysis().output_size_in_bytes < (
         S * 4 * dsi_cfg.num_planes * dsi_cfg.height * dsi_cfg.width)
+
+
+VGA = CameraModel(width=640, height=480, fx=560.0, fy=560.0, cx=320.0,
+                  cy=240.0)
+# each cell's sweep: camera, leading (maps[, cameras]), capacity, quantized
+CELL_SWEEPS = {
+    "davis240": (CameraModel(), (4,), 800, True),
+    "vga640": (VGA, (2,), 888, False),
+    "dsec_rig_fused": (VGA, (2, 2), 888, False),
+}
+
+
+def _cell_batch(sharding, lead, c) -> SegmentBatch:
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    s = lead[0]
+    return SegmentBatch(xy=sd(*lead, c, E, 2), valid=sd(*lead, c, E),
+                        frame_valid=sd(*lead, c), poses_R=sd(*lead, c, 3, 3),
+                        poses_t=sd(*lead, c, 3), ref_R=sd(s, 3, 3),
+                        ref_t=sd(s, 3))
+
+
+def _loop_trips(hlo: str) -> set[int]:
+    """Trip counts of the compiled program's loops: the constants their
+    conditions compare the induction variable against."""
+    consts = dict(re.findall(r"(%\S+) = s32\[\]\S* constant\((\d+)\)", hlo))
+    return {int(consts[c]) for c in re.findall(
+        r"compare\(%\S+, (%\S+)\), direction=LT, metadata=\{op_name=\"[^\"]*"
+        r"while/cond", hlo) if c in consts}
+
+
+def _unfused_shapes(hlo: str) -> set[str]:
+    """Shapes of the values the compiled program keeps in memory: every
+    instruction outside the computations that fusions call."""
+    fused = set(re.findall(r"calls=(%[\w.-]+)", hlo))
+    shapes, inside = set(), False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) ", line)
+        if head and not line.startswith(" "):
+            inside = head.group(1) in fused
+        elif not inside:
+            shapes.update(re.findall(r" = (\w+\[[\d,]*\])", line))
+    return shapes
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SWEEPS))
+def test_nearest_sweep_votes_four_frames_per_int8_dot(one_chip, cell):
+    """At each cell's shape the nearest matmul sweep contracts the
+    one-hot rows of 4 x E events in one s8 x s8 -> s32 dot a plane, its
+    vote loop runs C/4 trips, the one-hot rows stay fused into the
+    contraction (none is a value in memory) and the program fits the
+    chip."""
+    cam, lead, c, quantized = CELL_SWEEPS[cell]
+    dsi_cfg = DSIConfig.for_camera(cam)
+    nz, h, w = dsi_cfg.shape
+    k = VOTE_CHUNK_FRAMES * E
+    lowered = process_segments_batched.lower(
+        cam, dsi_cfg, _cell_batch(one_chip, lead, c),
+        EMVSOptions(quantized=quantized))
+    dots = re.findall(r"stablehlo.dot_general .*: \((tensor<[^>]*>), "
+                      r"(tensor<[^>]*>)\) -> (tensor<[^>]*>)", lowered.as_text())
+    assert (f"tensor<{nz}x{k}x{h}xi8>", f"tensor<{nz}x{k}x{w}xi8>",
+            f"tensor<{nz}x{h}x{w}xi32>") in dots
+    assert not [d for d in dots if d[2] == f"tensor<{nz}x{h}x{w}xf32>"]
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    trips = _loop_trips(hlo)
+    assert c // VOTE_CHUNK_FRAMES in trips and c not in trips
+    assert not [s for s in _unfused_shapes(hlo) if s.startswith(
+        (f"pred[{nz},{k},", f"s8[{nz},{k},"))]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 16e9 / 4
+
+
+def test_bilinear_sweep_keeps_its_float32_per_frame_dot(one_chip):
+    """Bilinear votes carry fractional weights: the sweep keeps one
+    float32 dot at full precision per frame, C trips."""
+    cam, lead, c, _ = CELL_SWEEPS["davis240"]
+    dsi_cfg = DSIConfig.for_camera(cam)
+    nz, h, w = dsi_cfg.shape
+    lowered = process_segments_batched.lower(
+        cam, dsi_cfg, _cell_batch(one_chip, lead, c),
+        EMVSOptions(voting="bilinear"))
+    text = lowered.as_text()
+    assert re.search(
+        rf"stablehlo.dot_general .*precision = \[HIGHEST, HIGHEST\] : "
+        rf"\(tensor<{nz}x{E}x{h}xf32>, tensor<{nz}x{E}x{w}xf32>\) -> "
+        rf"tensor<{nz}x{h}x{w}xf32>", text)
+    assert "xi8>" not in text
+    assert c in _loop_trips(lowered.compile().as_text())
